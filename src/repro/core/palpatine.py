@@ -57,6 +57,10 @@ class PalpatineConfig:
     cache_bytes: int = 32 * 1024 * 1024          # paper default working point
     preemptive_frac: float = 0.10
     mining: MiningParams = dataclasses.field(default_factory=MiningParams)
+    # where the vectorized engines walk the trees: "numpy" on the host or
+    # "jax" for the jitted device walk (the decision-side twin of
+    # ``mining.use_kernel``)
+    decision_backend: str = "numpy"
     algo: str = "vmsp"
     metastore_capacity: int = 10_000
     session_gap: float = 1.0                      # virtual seconds
@@ -103,7 +107,8 @@ class PalpatineClient:
         self.metastore = PatternMetastore(self.cfg.metastore_capacity,
                                           self.cfg.mining.max_len)
         self.engine = build_engine(PTreeIndex.build([]), self.cfg.heuristic,
-                                   use_vectorized=self.cfg.use_vectorized)
+                                   use_vectorized=self.cfg.use_vectorized,
+                                   backend=self.cfg.decision_backend)
         self.col_logger = AccessLogger(self.cfg.session_gap)
         # column patterns are instantiated with the *current* request's row,
         # so they are always walked progressively (one confirmed step ->
@@ -111,7 +116,8 @@ class PalpatineClient:
         self.col_engine = build_engine(
             PTreeIndex.build([]),
             HeuristicConfig("fetch_progressive", progressive_depth=2),
-            use_vectorized=self.cfg.use_vectorized)
+            use_vectorized=self.cfg.use_vectorized,
+            backend=self.cfg.decision_backend)
         self.col_metastore: Optional[PatternMetastore] = None
         self._ops_since_mine = 0
         self.mining_runs = 0

@@ -1,29 +1,27 @@
-"""Pallas TPU kernel: VMSP s-step join + per-session support count.
+"""Pallas TPU kernels: VMSP s-step join + per-session support count.
 
 The mining hot loop (paper §3.2: candidate support counting dominates
 sequential-pattern-mining runtime) is a bitwise AND of a prefix's extension
 slots against every candidate item's occurrence bitmap, followed by an
-"any bit set per session" reduction.
+"any bit set per session" reduction.  The work is bitwise, so it runs on
+the VPU; the support accumulator is carried across the sequential session
+grid dimension in the revisited output block, the standard Pallas
+reduction pattern.
 
-TPU adaptation: the sequence database's vertical bitmaps are laid out
-(K candidates, S sessions, W packed words).  The kernel tiles (K, S) into
-VMEM blocks — the whole word dimension rides along (W is small: sessions
-are ≤ W·32 accesses) — and runs the AND + reduce on the VPU.  The support
-accumulator is carried across the sequential S-tile grid dimension in the
-output block (revisited blocks accumulate), the standard Pallas reduction
-pattern.
+Two kernels:
 
-Blocks default to (8 candidates × 512 sessions × W words): one uint32 tile
-is 8·512·W·4 B = 16 KiB·W, three live blocks ≈ 48·W KiB ≪ VMEM, and both
-tile dims are multiples of the (8, 128) VPU lane grid.
-
-Two kernels share this layout:
-
-* ``sstep_join_support_pallas`` — per-prefix (1×K) join, returning joined
-  bitmaps + support (the DFS walker's primitive);
+* ``sstep_join_support_pallas`` — per-prefix (1×K) join over the
+  (K candidates, S sessions, W packed words) layout, returning joined
+  bitmaps + support (the DFS spill walker's primitive).  Blocks are
+  (8 candidates × 512 sessions × W words); W is the whole minor dim.
 * ``frontier_join_support_pallas`` — the level-synchronous miner's fused
-  (P×K) support join over a whole frontier of prefixes, 3-D grid tiling
-  (P, K) in parallel with the session dimension accumulated sequentially.
+  (P×K) support join over a whole frontier of prefixes.  Sessions are the
+  minor (lane) dimension: inputs are laid out (W, P, S) and (W, K, S), so
+  a (bP, bS) / (bK, bS) block fills whole (8, 128) vregs however few words
+  W a session needs, and the (bP, bK) support tile has K on lanes.  The
+  (bP, bK, bS) int32 hit temporary is 8·128·512·4 B = 2 MiB, well inside
+  the 16 MiB scoped-VMEM limit for any W (the words are OR-ed one at a
+  time).
 """
 
 from __future__ import annotations
@@ -33,20 +31,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["sstep_join_support_pallas", "frontier_join_support_pallas"]
 
 DEFAULT_BLOCK_K = 8
 DEFAULT_BLOCK_S = 512
 
-# frontier kernel tiles: the fused (bP, bK, bS, W) AND temporary is
-# 8·8·128·W·4 B = 32 KiB·W, comfortably inside VMEM, and the (bP, bK)
-# support tile matches the (8, 128)-lane VPU grid after broadcast
+# frontier kernel tiles: bS is the input blocks' lane dim and bK the
+# output block's, so both are multiples of 128 (or the whole dim)
 DEFAULT_BLOCK_P = 8
-DEFAULT_BLOCK_FK = 8
-DEFAULT_BLOCK_FS = 128
+DEFAULT_BLOCK_FK = 128
+DEFAULT_BLOCK_FS = 512
 
 
 def _kernel(slots_ref, cand_ref, joined_ref, support_ref):
@@ -104,7 +100,7 @@ def sstep_join_support_pallas(
             jax.ShapeDtypeStruct((k_items, n_sessions, n_words), jnp.uint32),
             jax.ShapeDtypeStruct((k_items, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -114,11 +110,13 @@ def sstep_join_support_pallas(
 
 def _frontier_kernel(slots_ref, cand_ref, support_ref):
     s_idx = pl.program_id(2)
-    slots = slots_ref[...]                      # (bP, bS, W) uint32
-    cand = cand_ref[...]                        # (bK, bS, W) uint32
-    joined = jnp.bitwise_and(slots[:, None, :, :], cand[None, :, :, :])
-    any_bit = jnp.any(joined != 0, axis=-1)     # (bP, bK, bS)
-    counts = jnp.sum(any_bit.astype(jnp.int32), axis=-1)  # (bP, bK)
+    hit = None
+    for w in range(slots_ref.shape[0]):         # W is tiny: unrolled
+        slots = slots_ref[w]                    # (bP, bS) uint32
+        cand = cand_ref[w]                      # (bK, bS) uint32
+        word = (slots[:, None, :] & cand[None, :, :]) != 0   # (bP, bK, bS)
+        hit = word if hit is None else hit | word
+    counts = jnp.sum(hit.astype(jnp.int32), axis=-1)         # (bP, bK)
 
     @pl.when(s_idx == 0)
     def _init():
@@ -141,7 +139,7 @@ def frontier_join_support_pallas(
     block_s: int = DEFAULT_BLOCK_FS,
     interpret: bool = False,
 ):
-    """Frontier-batched support join: (P,S,W) × (K,S,W) -> (P,K) int32.
+    """Frontier-batched support join: (W,P,S) × (W,K,S) -> (P,K) int32.
 
     The level-synchronous miner's fused join — one launch counts support for
     every (prefix, candidate-item) pair of a whole lattice level.  The grid
@@ -150,12 +148,13 @@ def frontier_join_support_pallas(
     are deliberately not written back: the miner materializes them only for
     the surviving pairs.
 
-    Inputs must be pre-padded: P % block_p == K % block_k == S % block_s == 0
-    (the ops.py wrapper pads; padding rows/sessions contribute zero support).
+    Inputs are session-minor and must be pre-padded: P % block_p ==
+    K % block_k == S % block_s == 0 (the ops.py wrapper transposes and
+    pads; padding rows/sessions contribute zero support).
     """
-    p_prefixes, n_sessions, n_words = slots.shape
-    k_items = cand.shape[0]
-    assert cand.shape == (k_items, n_sessions, n_words)
+    n_words, p_prefixes, n_sessions = slots.shape
+    k_items = cand.shape[1]
+    assert cand.shape == (n_words, k_items, n_sessions)
     assert (p_prefixes % block_p == 0 and k_items % block_k == 0
             and n_sessions % block_s == 0)
     grid = (p_prefixes // block_p, k_items // block_k, n_sessions // block_s)
@@ -164,13 +163,13 @@ def frontier_join_support_pallas(
         _frontier_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_p, block_s, n_words), lambda p, k, s: (p, s, 0)),
-            pl.BlockSpec((block_k, block_s, n_words), lambda p, k, s: (k, s, 0)),
+            pl.BlockSpec((n_words, block_p, block_s), lambda p, k, s: (0, p, s)),
+            pl.BlockSpec((n_words, block_k, block_s), lambda p, k, s: (0, k, s)),
         ],
         # revisited across the s grid dim -> accumulates
         out_specs=pl.BlockSpec((block_p, block_k), lambda p, k, s: (p, k)),
         out_shape=jax.ShapeDtypeStruct((p_prefixes, k_items), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -4,8 +4,12 @@
 One XLA program advances every live prefetch context by the requested
 item — a probability-matrix walk over the flattened pattern forest:
 
-* the edge table (sorted ``parent * item_stride + item`` keys) resolves
-  all C confirmed positions with one ``searchsorted``;
+* each context's confirmed node owns the contiguous, item-sorted slice
+  ``[edge_first[v], edge_first[v] + n_children[v])`` of the edge table;
+  all C contexts look the item up in their slices with one batched
+  fixed-depth binary search.  Every index and item stays an int32 — no
+  ``parent * item_stride + item`` key, which would wrap once node ids ×
+  vocabulary pass 2^31 with x64 off;
 * wave selection broadcasts each emitting context's depth band and DFS
   preorder interval against the whole node table, yielding a dense
   (C, N) wave mask whose row-major nonzeros are exactly the scalar
@@ -32,27 +36,36 @@ __all__ = ["decision_walk_step", "top_k_frontier"]
 
 
 @partial(jax.jit,
-         static_argnames=("p_depth", "item_stride", "depth_stride"))
-def decision_walk_step(edge_keys, edge_child, items, depth, pre, post,
-                       n_children, tree_start, tree_max_depth, level_key,
-                       nodes, trees, fetched, stamps, alive, item, op,
-                       *, p_depth: int, item_stride: int,
-                       depth_stride: int):
+         static_argnames=("p_depth", "depth_stride", "search_steps"))
+def decision_walk_step(edge_item, edge_child, edge_first, items, depth,
+                       pre, post, n_children, tree_start, tree_max_depth,
+                       level_key, nodes, trees, fetched, alive, item,
+                       *, p_depth: int, depth_stride: int,
+                       search_steps: int):
     """Advance C (padded) contexts by ``item``; returns the new context
     state plus the dense (C, N) wave mask.
 
-    Dead/padding rows carry ``alive=False`` and never match, emit, or
-    resurrect — zero-padding is decision-neutral, mirroring the
-    support-neutral padding contract of ``frontier_join_support``."""
-    keys = nodes * item_stride + item
-    pos = jnp.searchsorted(edge_keys, keys)
-    posc = jnp.clip(pos, 0, edge_keys.shape[0] - 1)
-    in_vocab = (item >= 0) & (item < item_stride)
-    found = alive & in_vocab & (edge_keys[posc] == keys)
-    child = edge_child[posc]
+    ``item`` is -1 when it lies outside the forest's vocabulary (it then
+    matches no edge and no root).  ``search_steps`` must be at least the
+    bit length of the largest ``n_children``.  Dead/padding rows carry
+    ``alive=False`` and never match, emit, or resurrect — zero-padding is
+    decision-neutral, mirroring the support-neutral padding contract of
+    ``frontier_join_support``."""
+    last = edge_item.shape[0] - 1
+    first = edge_first[nodes]
+    end = first + n_children[nodes]
+    lo, hi = first, end
+    for _ in range(search_steps):       # lower bound of item in [lo, hi)
+        active = lo < hi
+        mid = (lo + hi) // 2
+        less = edge_item[jnp.minimum(mid, last)] < item
+        lo = jnp.where(active & less, mid + 1, lo)
+        hi = jnp.where(active & ~less, mid, hi)
+    pos = jnp.minimum(lo, last)
+    found = alive & (lo < end) & (edge_item[pos] == item)
+    child = edge_child[pos]
     roots = tree_start[trees]
-    stay = (alive & in_vocab & ~found & (nodes == roots)
-            & (items[nodes] == item))
+    stay = alive & ~found & (nodes == roots) & (items[nodes] == item)
     new_nodes = jnp.where(found, child, nodes)
     cdepth = depth[new_nodes]
     target = cdepth + p_depth
@@ -61,15 +74,13 @@ def decision_walk_step(edge_keys, edge_child, items, depth, pre, post,
                           | (n_children[new_nodes] == 0))
     new_alive = (found & ~dies_after) | stay
     new_fetched = jnp.where(emit, target, fetched)
-    new_stamps = jnp.where(found | stay, op, stamps)
     lo = (trees * depth_stride + fetched + 1)[:, None]
     hi = (trees * depth_stride + target)[:, None]
     band = (level_key[None, :] >= lo) & (level_key[None, :] <= hi)
     sub = ((pre[None, :] >= pre[new_nodes][:, None])
            & (pre[None, :] < post[new_nodes][:, None]))
     wave_mask = band & sub & emit[:, None]
-    return (new_nodes, new_fetched, new_stamps, new_alive, found, stay,
-            wave_mask)
+    return new_nodes, new_fetched, new_alive, found, stay, wave_mask
 
 
 @partial(jax.jit, static_argnames=("k",))

@@ -1,11 +1,11 @@
 """Public wrappers for the jitted decision walk.
 
 ``device_forest`` ships one mining generation's :class:`FlatForest` to
-the device (empty edge tables get an unmatchable sentinel so the jitted
-``searchsorted`` stays shape-safe); ``decision_walk`` pads the live
-context state to the engine's ``max_contexts`` — keeping every shape
-static per generation, one compile each — runs the jitted step, and
-unpads back to the compact numpy state dict the core engine consumes.
+the device as int32 arrays (the device runs with x64 off) and refuses a
+forest whose ids would not fit; ``decision_walk`` pads the live context
+state to the engine's ``max_contexts`` — keeping every shape static per
+generation, one compile each — runs the jitted step, and unpads back to
+the compact numpy state dict the core engine consumes.
 """
 
 from __future__ import annotations
@@ -18,28 +18,50 @@ from .decision_walk import decision_walk_step, top_k_frontier
 
 __all__ = ["device_forest", "decision_walk", "top_k_frontier"]
 
-_SENTINEL = np.iinfo(np.int64).max
+_INT32_MAX = np.iinfo(np.int32).max
 
 
 class DeviceForest:
-    """Per-generation device-resident FlatForest arrays."""
+    """Per-generation device-resident FlatForest arrays (int32).
+
+    The edge table is kept in its ``(parent, item)`` sort order as two
+    parallel arrays, ``edge_item`` and ``edge_child``; ``edge_first[v]``
+    is where node ``v``'s slice of it starts (its ``n_children`` edges
+    are contiguous because the table is sorted by parent first)."""
 
     def __init__(self, flat):
-        ek = flat.edge_keys
-        ec = flat.edge_child
-        if ek.size == 0:
-            ek = np.array([_SENTINEL], np.int64)
-            ec = np.zeros(1, np.int64)
-        self.edge_keys = jnp.asarray(ek)
-        self.edge_child = jnp.asarray(ec)
-        self.items = jnp.asarray(flat.items)
-        self.depth = jnp.asarray(flat.depth)
-        self.pre = jnp.asarray(flat.pre)
-        self.post = jnp.asarray(flat.post)
-        self.n_children = jnp.asarray(flat.n_children)
-        self.tree_start = jnp.asarray(flat.tree_start)
-        self.tree_max_depth = jnp.asarray(flat.tree_max_depth)
-        self.level_key = jnp.asarray(flat.level_key)
+        n = flat.n_nodes
+        # 2 * n bounds the search's lo + hi and every id, pre/post rank
+        # and edge index; level_key and the items bound the rest
+        biggest = max(2 * n, flat.item_stride,
+                      int(flat.level_key.max()) if n else 0)
+        if biggest > _INT32_MAX:
+            raise OverflowError(
+                f"forest of {n} nodes over a vocabulary of "
+                f"{flat.item_stride} items does not fit the device walk's "
+                f"int32 ids")
+        # an empty edge table gets one unreachable entry (no node owns
+        # it: every n_children is 0) so the gathers stay shape-safe
+        edge_child = flat.edge_child if flat.edge_child.size else np.zeros(1)
+        edge_item = (flat.items[flat.edge_child] if flat.edge_child.size
+                     else np.full(1, -1))
+        self.edge_item = _i32(edge_item)
+        self.edge_child = _i32(edge_child)
+        self.edge_first = _i32(np.cumsum(flat.n_children) - flat.n_children)
+        self.search_steps = max(1, int(flat.n_children.max(initial=0))
+                                .bit_length())
+        self.items = _i32(flat.items)
+        self.depth = _i32(flat.depth)
+        self.pre = _i32(flat.pre)
+        self.post = _i32(flat.post)
+        self.n_children = _i32(flat.n_children)
+        self.tree_start = _i32(flat.tree_start)
+        self.tree_max_depth = _i32(flat.tree_max_depth)
+        self.level_key = _i32(flat.level_key)
+
+
+def _i32(a) -> jnp.ndarray:
+    return jnp.asarray(np.asarray(a).astype(np.int32))
 
 
 def device_forest(flat) -> DeviceForest:
@@ -77,24 +99,25 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
     c = max_contexts or max(n, 1)
     pad = c - n
 
-    def _ctx(a, fill=0):
-        a = np.asarray(a, np.int64)
-        return jnp.asarray(np.pad(a, (0, pad), constant_values=fill))
+    def _ctx(a):
+        return _i32(np.pad(np.asarray(a), (0, pad)))
 
     alive = np.zeros(c, bool)
     alive[:n] = True
     out = decision_walk_step(
-        jf.edge_keys, jf.edge_child, jf.items, jf.depth, jf.pre, jf.post,
-        jf.n_children, jf.tree_start, jf.tree_max_depth, jf.level_key,
-        _ctx(nodes), _ctx(trees), _ctx(fetched),
-        _ctx(np.zeros(n, np.int64)), jnp.asarray(alive), item, 0,
-        p_depth=p_depth, item_stride=flat.item_stride,
-        depth_stride=flat.depth_stride)
-    new_nodes, new_fetched, _, new_alive, found, stay, mask = (
+        jf.edge_item, jf.edge_child, jf.edge_first, jf.items, jf.depth,
+        jf.pre, jf.post, jf.n_children, jf.tree_start, jf.tree_max_depth,
+        jf.level_key, _ctx(nodes), _ctx(trees), _ctx(fetched),
+        jnp.asarray(alive), item if 0 <= item < flat.item_stride else -1,
+        p_depth=p_depth, depth_stride=flat.depth_stride,
+        search_steps=jf.search_steps)
+    new_nodes, new_fetched, new_alive, found, stay, mask = (
         np.asarray(o) for o in out)
     _, wave_nodes = np.nonzero(mask[:n])
+    i64 = np.int64
     return {
-        "found": found[:n], "stay": stay[:n], "nodes": new_nodes[:n],
-        "alive": new_alive[:n], "fetched": new_fetched[:n],
-        "wave_nodes": wave_nodes.astype(np.int64),
+        "found": found[:n], "stay": stay[:n],
+        "nodes": new_nodes[:n].astype(i64),
+        "alive": new_alive[:n], "fetched": new_fetched[:n].astype(i64),
+        "wave_nodes": wave_nodes.astype(i64),
     }

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 __all__ = ["flash_attention_pallas"]
 
 DEFAULT_BLOCK_Q = 512
@@ -162,7 +160,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # normalizer l
             pltpu.VMEM((block_q, d), jnp.float32),       # accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

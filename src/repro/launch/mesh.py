@@ -2,27 +2,18 @@
 
 A function (not a module-level constant) so importing this module never
 touches jax device state — the dry-run must set XLA_FLAGS before any jax
-initialization.
-
-Compat: ``jax.sharding.AxisType`` only exists on newer jax (>= 0.5); on
-0.4.x ``jax.make_mesh`` takes no ``axis_types`` argument.  ``_axis_types``
-returns the kwargs to splat so both paths build identical Auto meshes.
-``make_abstract_mesh`` papers over the 0.4.x ``AbstractMesh`` constructor,
-which takes ``((name, size), ...)`` pairs instead of ``(shape, names)``.
+initialization.  Every mesh is built with ``Auto`` axis types.
 """
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_abstract_mesh"]
+__all__ = ["make_production_mesh", "make_local_mesh"]
 
 
-def _axis_types(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,18 +22,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     be re-bound to pipeline stages, see training/pipeline.py)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many (host) devices exist — used by tests."""
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_types(2))
-
-
-def make_abstract_mesh(shape: tuple, axes: tuple):
-    """Device-free mesh for spec construction on hosts without the chips."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))

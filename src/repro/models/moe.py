@@ -25,8 +25,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
-
 from .layers import dense_init
 
 __all__ = ["moe_init", "moe_apply", "moe_capacity"]
@@ -213,7 +211,7 @@ def moe_apply_ep(p, cfg, x, fsdp_weights: bool = True):
     d_model = p["w1"].shape[1]
     w_d = "data" if (fsdp_weights and d_model % n_data == 0) else None
     gather_weights = w_d == "data"
-    return shard_map(
+    return jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(x_spec, P(),

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 __all__ = ["pipeline_apply", "bubble_fraction"]
 
 
@@ -88,7 +86,7 @@ def pipeline_apply(stage_fn, stage_params, x, *, mesh, axis: str):
 
     other_axes = [a for a in mesh.axis_names if a != axis]
     pspec_params = P(axis)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: pspec_params, stage_params), P()),

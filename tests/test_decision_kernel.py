@@ -82,6 +82,89 @@ def test_decision_walk_interpret_escape_hatch(seed):
                 err_msg=key)
 
 
+#: a paper-scale item vocabulary: with ~10^3 nodes, node id × item_stride
+#: passes 2^31, where a packed ``parent * item_stride + item`` int32 key
+#: would wrap
+BIG_VOCAB = 3_000_000
+
+
+def big_vocab_index(seed, n_patterns=300):
+    """Random patterns over BIG_VOCAB items; a few shared roots and second
+    items make the trees branch."""
+    rng = np.random.default_rng(seed)
+    roots = rng.integers(0, BIG_VOCAB, size=6)
+    seconds = rng.integers(0, BIG_VOCAB, size=4)
+    pats = []
+    for _ in range(n_patterns):
+        tail = rng.integers(0, BIG_VOCAB, size=int(rng.integers(1, 6)))
+        items = (int(roots[rng.integers(len(roots))]),
+                 int(seconds[rng.integers(len(seconds))]),
+                 *(int(x) for x in tail))
+        pats.append(Pattern(items, int(rng.integers(2, 40))))
+    return PTreeIndex.build(pats)
+
+
+@pytest.mark.parametrize("seed", range(2))
+def test_decision_walk_ops_match_ref_past_int32_keys(seed):
+    rng = np.random.default_rng(seed)
+    flat = big_vocab_index(seed).flatten()
+    assert (flat.n_nodes - 1) * flat.item_stride > 2 ** 31
+    jf = dw_ops.device_forest(flat)
+    for _ in range(8):
+        nodes, trees, fetched = live_states(flat, rng, 16)
+        # half the trials step by a real child item of a live node
+        v = int(nodes[rng.integers(len(nodes))])
+        kids = flat.items[flat.first_child[v]:
+                          flat.first_child[v] + flat.n_children[v]]
+        item = (int(kids[rng.integers(len(kids))]) if rng.random() < 0.5
+                else int(rng.integers(-2, BIG_VOCAB + 3)))
+        a = dw_ops.decision_walk(jf, flat, nodes, trees, fetched,
+                                 item, 2, max_contexts=32)
+        b = dw_ref.decision_walk_ref(flat, nodes, trees, fetched, item, 2)
+        for key in ("found", "stay", "nodes", "alive", "fetched",
+                    "wave_nodes"):
+            np.testing.assert_array_equal(
+                np.asarray(a[key]), np.asarray(b[key]), err_msg=key)
+
+
+@pytest.mark.parametrize("p_depth", [1, 2, 3])
+def test_jax_backend_lockstep_past_int32_keys(p_depth):
+    """The device walk agrees with the numpy engine op for op on a forest
+    whose packed edge keys would overflow int32."""
+    index = big_vocab_index(7)
+    flat = index.flatten()
+    assert (flat.n_nodes - 1) * flat.item_stride > 2 ** 31
+    cfg = HeuristicConfig("fetch_progressive", progressive_depth=p_depth)
+    host = VectorizedPrefetchEngine(index, cfg, max_contexts=16)
+    dev = VectorizedPrefetchEngine(index, cfg, max_contexts=16,
+                                   backend="jax")
+    waves = 0
+    for i, item in enumerate(seqb_stream(p_depth, index, n_ops=240,
+                                         alphabet=BIG_VOCAB)):
+        a, b = host.on_request(item), dev.on_request(item)
+        assert a == b, (i, item, a, b)
+        assert host.n_live == dev.n_live
+        waves += bool(a)
+    assert waves > 20
+
+
+@pytest.mark.parametrize("cfg", HEURISTIC_CFGS, ids=lambda c: c.name)
+def test_jax_backend_lockstep_on_empty_forest(cfg):
+    index = PTreeIndex.build([])
+    host = VectorizedPrefetchEngine(index, cfg, max_contexts=8)
+    dev = VectorizedPrefetchEngine(index, cfg, max_contexts=8,
+                                   backend="jax")
+    for item in (-1, 0, 3, 2 ** 31 + 5, 7):
+        assert host.on_request(item) == dev.on_request(item) == []
+        assert host.n_live == dev.n_live == 0
+
+
+def test_device_forest_refuses_ids_past_int32():
+    flat = PTreeIndex.build([Pattern((1, 2, 2 ** 31 + 5), 3)]).flatten()
+    with pytest.raises(OverflowError):
+        dw_ops.device_forest(flat)
+
+
 def test_decision_walk_empty_edge_table():
     flat = PTreeIndex.build([]).flatten()
     jf = dw_ops.device_forest(flat)

@@ -51,10 +51,14 @@ def test_bitmap_support_sparse_and_empty():
 @pytest.mark.parametrize("p_prefixes,k_items,n_sessions,n_words", [
     (1, 1, 7, 1),
     (5, 9, 100, 2),
-    (8, 8, 128, 1),      # exact blocks
+    (8, 8, 128, 1),      # whole-dim blocks (P, K below the defaults)
     (9, 17, 130, 3),     # off-by-one padding in all three dims
     (16, 32, 512, 1),
     (3, 2, 1, 1),
+    (8, 128, 512, 1),    # exact default blocks
+    (9, 130, 700, 2),    # K past one 128-lane block, not a multiple
+    (3, 257, 1030, 4),   # K and S padded, every word of a 4-word session
+    (2, 600, 64, 1),     # K >= 512, as in a SEQB level-1 join
 ])
 def test_frontier_join_support_matches_ref(p_prefixes, k_items, n_sessions,
                                            n_words):

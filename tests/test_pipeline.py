@@ -23,9 +23,9 @@ _PROG = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.training.pipeline import pipeline_apply
-    from repro.launch.mesh import _axis_types
 
-    mesh = jax.make_mesh((4,), ("stage",), **_axis_types(1))
+    mesh = jax.make_mesh((4,), ("stage",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     S, M, MB, D = 4, 6, 2, 8
     w = jnp.asarray(rng.standard_normal((S, D, D)) * 0.3, jnp.float32)

@@ -9,10 +9,10 @@ import textwrap
 
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch.mesh import make_abstract_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import param_shapes
 from repro.sharding import rules
 
@@ -30,7 +30,7 @@ def prod_mesh():
     devs = jax.devices()
     if len(devs) >= 256:
         return make_production_mesh()
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_dense_tp_fsdp_specs(prod_mesh):
