@@ -1,12 +1,13 @@
 """Palpascope observability layer: causal tracing, metrics, attribution.
 
-Zero-dependency (stdlib + the simulation's own virtual clocks) and
+Zero-dependency at import (stdlib + the simulation's own virtual
+clocks; only an active host profile imports ``jax.profiler``) and
 off by default: every request-path hook goes through a module-level
 :data:`NULL_TRACER` whose methods are constant-returning no-ops, so an
 untraced run pays a handful of attribute lookups per op (gated in
 ``bench_overhead.py`` as ``tracing_overhead_ratio``).
 
-Three instruments, one module:
+Four instruments, one module:
 
 * **Causal tracing** — a :class:`Span` tree per client op, threaded
   through coordinator routing, node RPCs, cache lookups, the decision
@@ -32,6 +33,14 @@ Three instruments, one module:
   evicted-unused mass, so the benches can export ``attr_*`` keys and
   the sum of per-pattern hits provably equals the cache's
   ``prefetch_hits`` counter (pinned by a tier-1 test).
+* **Host profile** — host-clock seconds, calls and child time per
+  ``palp.*`` span, and plain counters, for the served path's layers
+  (decision engine, decision-walk kernel wrapper).  Off by default
+  through :data:`NULL_HOST_PROFILE`; :func:`set_host_profile` installs
+  a :class:`HostProfile`, each of whose spans is also a
+  ``jax.profiler.TraceAnnotation``, so it lands in a profiler trace on
+  the same clock as the device's operations.  :data:`host_clock` is
+  the one host clock of ``src/repro/core``.
 
 Sampling: ``Tracer(sample=1/N, seed=...)`` keeps a deterministic 1-in-N
 subset of root spans — the selection is a pure function of ``(seed,
@@ -42,10 +51,12 @@ this).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import json
 import math
+import time
 from collections import deque
 from typing import Any, Iterable, Optional, Sequence
 
@@ -55,6 +66,8 @@ __all__ = [
     "percentile", "latency_percentiles",
     "PrefetchCause", "AttributionTable",
     "span_kind_breakdown", "critical_path",
+    "host_clock", "HostProfile", "NullHostProfile", "NULL_HOST_PROFILE",
+    "host_profile", "set_host_profile",
 ]
 
 # ---------------------------------------------------------------------------
@@ -76,6 +89,16 @@ SPAN_SERVICE = "service"              # node-side service interval
 SPAN_WRITE = "write"                  # coordinator replicated write
 SPAN_MEMBERSHIP = "membership_move"   # ring-change range transfer
 
+# host-profile spans (host clock; ``palp.`` keeps them apart from any
+# annotation a caller writes around the program in the same trace)
+SPAN_HOST_DECIDE = "palp.decide"      # one decision engine call
+SPAN_HOST_WALK = "palp.walk"          # one jitted decision-walk call
+SPAN_HOST_WALK_UPLOAD = "palp.walk.upload"      # pad + host->device
+SPAN_HOST_WALK_DISPATCH = "palp.walk.dispatch"  # jitted call returns
+SPAN_HOST_WALK_WAIT = "palp.walk.wait"          # block_until_ready
+SPAN_HOST_WALK_READBACK = "palp.walk.readback"  # device->host copies
+SPAN_HOST_WALK_UNPACK = "palp.walk.unpack"      # nonzero, slice, cast
+
 # zero-duration events attached to the innermost open span
 EVENT_HINT = "hint"
 EVENT_SLOPPY = "sloppy_write"
@@ -91,9 +114,6 @@ EVENT_SHED = "prefetch_shed"
 # metric names (registry keys; benches snapshot these per phase)
 METRIC_READ_LATENCY = "read_latency_s"
 METRIC_OPS = "ops"
-METRIC_PREFETCH_ISSUED = "prefetch_issued"
-METRIC_PREFETCH_HITS = "prefetch_hits"
-METRIC_RPC_TIMEOUTS = "rpc_timeouts"
 METRIC_STALE_READS = "stale_reads"
 METRIC_DEMAND_WAIT = "demand_wait_s"
 METRIC_STORE_FETCHES = "store_fetches"
@@ -101,6 +121,11 @@ METRIC_SESSIONS = "sessions"
 METRIC_PREFILL_S = "prefill_s"
 METRIC_DECODE_S = "decode_s"
 METRIC_TOKENS = "tokens"
+# host-profile counters of the decision walk's transfers
+METRIC_WALK_H2D_COPIES = "palp.walk.h2d_copies"
+METRIC_WALK_H2D_BYTES = "palp.walk.h2d_bytes"
+METRIC_WALK_D2H_COPIES = "palp.walk.d2h_copies"
+METRIC_WALK_D2H_BYTES = "palp.walk.d2h_bytes"
 
 REGISTERED_NAMES = frozenset(
     v for k, v in list(globals().items())
@@ -324,6 +349,116 @@ class Tracer:
     def dump(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.export(), f, indent=1, sort_keys=True)
+
+
+# ---------------------------------------------------------------------------
+# Host profile (host clock, off by default)
+# ---------------------------------------------------------------------------
+
+#: the one host clock of ``src/repro/core``: host telemetry only (the
+#: host profile, mining-round wall time), never simulated time or results
+# palplint: disable=PALP001 -- host telemetry, not simulation time
+host_clock = time.perf_counter
+
+
+#: the do-nothing ``with`` target every null-profile span returns
+_NULL_CONTEXT = contextlib.nullcontext()
+
+
+class NullHostProfile:
+    """Host profiling disabled: the default.  ``span`` returns one
+    prebuilt no-op context manager and ``count`` returns at once, so an
+    unprofiled call allocates nothing and reads no clock."""
+
+    active = False
+
+    def span(self, name: str) -> contextlib.nullcontext:
+        return _NULL_CONTEXT
+
+    def count(self, name: str, n: int = 1) -> None:
+        return None
+
+
+NULL_HOST_PROFILE = NullHostProfile()
+
+
+class _HostSpan:
+    """One open host-profile span; closing it books its host seconds,
+    its children's total and one call under its name."""
+
+    __slots__ = ("profile", "name", "annotation", "t0", "child")
+
+    def __init__(self, profile: "HostProfile", name: str):
+        self.profile = profile
+        self.name = name
+
+    def __enter__(self) -> "_HostSpan":
+        p = self.profile
+        self.annotation = p._annotation(self.name)
+        self.annotation.__enter__()
+        p._stack.append(self)
+        self.child = 0.0
+        self.t0 = p._clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        p = self.profile
+        dt = p._clock() - self.t0
+        p._stack.pop()
+        self.annotation.__exit__(*exc)
+        name = self.name
+        p.seconds[name] = p.seconds.get(name, 0.0) + dt
+        p.calls[name] = p.calls.get(name, 0) + 1
+        p.child_seconds[name] = p.child_seconds.get(name, 0.0) + self.child
+        if p._stack:
+            p._stack[-1].child += dt
+        return False
+
+
+class HostProfile:
+    """Host seconds, calls and child seconds per span name, and plain
+    counters.  Each span is also a ``jax.profiler.TraceAnnotation`` of
+    its name (``jax.profiler`` is imported here, not with the module),
+    so a profiler trace shows it on the host plane, on the device's
+    clock.  ``clock`` (for tests) defaults to :data:`host_clock`.
+    Single-threaded, as the client is: spans nest on one stack."""
+
+    active = True
+
+    def __init__(self, clock=None):
+        from jax.profiler import TraceAnnotation
+
+        self._clock = clock or host_clock
+        self._annotation = TraceAnnotation
+        self._stack: list[_HostSpan] = []
+        self.seconds: dict[str, float] = {}
+        self.calls: dict[str, int] = {}
+        self.child_seconds: dict[str, float] = {}
+        self.counters: dict[str, int] = {}
+
+    def span(self, name: str) -> _HostSpan:
+        return _HostSpan(self, name)
+
+    def count(self, name: str, n: int = 1) -> None:
+        self.counters[name] = self.counters.get(name, 0) + n
+
+    def self_seconds(self, name: str) -> float:
+        """``name``'s host seconds less those of the spans opened
+        directly inside it."""
+        return self.seconds.get(name, 0.0) - self.child_seconds.get(name, 0.0)
+
+
+#: the profile the program's spans and counters go to; read it as
+#: ``obs.host_profile`` at the call, never bind it at import
+host_profile = NULL_HOST_PROFILE
+
+
+def set_host_profile(profile) -> "HostProfile | NullHostProfile":
+    """The one switch: make ``profile`` current (``NULL_HOST_PROFILE``
+    turns profiling off) and return the one it replaced, to restore."""
+    global host_profile
+    old, host_profile = host_profile, profile
+    return old
 
 
 # ---------------------------------------------------------------------------

@@ -10,19 +10,20 @@ unmodified client (direct store access), used as the paper's baseline.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Optional, Sequence
 
 from .backstore import Clock, SimulatedDKVStore
 from .cache import TwoSpaceCache
 from .decision import build_engine
 from .heuristics import HeuristicConfig
+from . import obs
 from .metastore import PatternMetastore
 from .obs import (
     NULL_TRACER,
     SPAN_CACHE,
     SPAN_DECISION,
     SPAN_DEMAND,
+    SPAN_HOST_DECIDE,
     SPAN_OP,
     SPAN_PREFETCH,
     EVENT_SHED,
@@ -39,12 +40,6 @@ from .ptree import PTreeIndex
 from .sessions import AccessLogger
 
 __all__ = ["PalpatineConfig", "PalpatineClient", "BaselineClient"]
-
-#: `mining_wall_time` reports *host* seconds spent in the miner — pure
-#: telemetry that never feeds simulated time or mined results; the one
-#: real-clock read stays behind a named alias so it is grep-able
-# palplint: disable=PALP001 -- host mining telemetry, not simulation time
-_telemetry_clock = time.perf_counter
 
 #: cache bookkeeping cost per request (in-memory hash + LRU on the paper's
 #: 3.4 GHz Xeon) — what a cache hit costs instead of a network round trip.
@@ -121,7 +116,13 @@ class PalpatineClient:
         self.col_metastore: Optional[PatternMetastore] = None
         self._ops_since_mine = 0
         self.mining_runs = 0
+        #: host seconds of the mining rounds, from ``mine_now``'s entry to
+        #: the new trees' install (``rebuild_wall_time``: the part spent
+        #: in ``populate``, ``PTreeIndex.build`` and ``replace_index`` of
+        #: both metastores) -- telemetry on ``obs.host_clock`` that never
+        #: feeds simulated time or mined results
         self.mining_wall_time = 0.0
+        self.rebuild_wall_time = 0.0
         # packed-bitmap reuse across mining runs: {"main"/"col": (fp, vb)}
         self._vb_cache: dict = {}
         self._last_mine_events: Optional[int] = None
@@ -344,12 +345,12 @@ class PalpatineClient:
     def mine_now(self, use_dynamic_minsup: bool = True) -> int:
         """Run the Data Mining Engine on the backlog, furnish the metastore,
         rebuild the probabilistic trees.  Returns #patterns stored."""
+        t0 = obs.host_clock()
         if self.cfg.column_mining:
             self._mine_columns(use_dynamic_minsup)
         db = self.logger.snapshot()
         if self.cfg.online_mine_every is not None:
             db = db.tail(self.cfg.online_tail_sessions)
-        t0 = _telemetry_clock()
         if use_dynamic_minsup:
             floor_count = self._floor_count(db, self.cfg.dynamic_minsup_floor)
             vb = self._cached_bitmaps(self.logger, db, floor_count, "main")
@@ -368,13 +369,16 @@ class PalpatineClient:
             if vb is None:
                 vb = self._build_bitmaps(self.logger, db, count, "main")
             patterns = mine(db, self.cfg.mining, self.cfg.algo, vb=vb)
-        self.mining_wall_time += _telemetry_clock() - t0
         self.mining_runs += 1
         self._last_mine_events = self.logger.n_events
         # a sequence observed once is not a pattern: support >= 2 sessions
         patterns = [p for p in patterns if p.support >= 2]
+        t1 = obs.host_clock()
         self.metastore.populate(patterns)
         self.engine.replace_index(PTreeIndex.build(self.metastore))
+        t2 = obs.host_clock()
+        self.rebuild_wall_time += t2 - t1
+        self.mining_wall_time += t2 - t0
         self._last_mine_generation = self.metastore.generation
         return len(self.metastore)
 
@@ -429,11 +433,13 @@ class PalpatineClient:
                 vb = self._build_bitmaps(self.col_logger, db, count, "col")
             patterns = mine(db, self.cfg.mining, self.cfg.algo, vb=vb)
         patterns = [p for p in patterns if p.support >= 2]
+        t0 = obs.host_clock()
         ms = PatternMetastore(self.cfg.metastore_capacity,
                               self.cfg.mining.max_len)
         ms.populate(patterns)
         self.col_metastore = ms
         self.col_engine.replace_index(PTreeIndex.build(ms))
+        self.rebuild_wall_time += obs.host_clock() - t0
 
     def _prefetch_columns(self, container, now: float) -> None:
         """Instantiate predicted (table, column) containers with the
@@ -443,7 +449,8 @@ class PalpatineClient:
             return
         row = key[1]
         gen_iid = self.col_logger.db.item_id(self._generalize(container))
-        targets = self.col_engine.on_request(gen_iid)
+        with obs.host_profile.span(SPAN_HOST_DECIDE):
+            targets = self.col_engine.on_request(gen_iid)
         if not targets:
             return
         if self.store.backlog(now) > self.cfg.backlog_cap:
@@ -491,7 +498,8 @@ class PalpatineClient:
             tr.event(EVENT_SHED, now)
             return  # background channel(s) saturated: shed prefetch load
         dsp = tr.span(SPAN_DECISION, now)
-        targets = self.engine.on_request(iid)
+        with obs.host_profile.span(SPAN_HOST_DECIDE):
+            targets = self.engine.on_request(iid)
         causes = (self.engine.last_attribution() or [None] * len(targets)) \
             if targets else []
         if dsp.live:

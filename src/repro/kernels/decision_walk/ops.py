@@ -5,13 +5,19 @@ the device as int32 arrays (the device runs with x64 off) and refuses a
 forest whose ids would not fit; ``decision_walk`` pads the live context
 state to the engine's ``max_contexts`` — keeping every shape static per
 generation, one compile each — runs the jitted step, and unpads back to
-the compact numpy state dict the core engine consumes.
+the compact numpy state dict the core engine consumes.  Under an active
+host profile (:mod:`repro.core.obs`) a jitted call is the span
+``palp.walk``, split into upload, dispatch, wait, readback and unpack,
+with its copies each way and their bytes counted.
 """
 
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+
+from repro.core import obs
 
 from . import ref as _ref
 from .decision_walk import decision_walk_step, top_k_frontier
@@ -96,28 +102,43 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
         return {"found": f, "stay": f.copy(), "nodes": z,
                 "alive": f.copy(), "fetched": z.copy(),
                 "wave_nodes": np.empty(0, np.int64)}
-    c = max_contexts or max(n, 1)
-    pad = c - n
-
-    def _ctx(a):
-        return _i32(np.pad(np.asarray(a), (0, pad)))
-
-    alive = np.zeros(c, bool)
-    alive[:n] = True
-    out = decision_walk_step(
-        jf.edge_item, jf.edge_child, jf.edge_first, jf.items, jf.depth,
-        jf.pre, jf.post, jf.n_children, jf.tree_start, jf.tree_max_depth,
-        jf.level_key, _ctx(nodes), _ctx(trees), _ctx(fetched),
-        jnp.asarray(alive), item if 0 <= item < flat.item_stride else -1,
-        p_depth=p_depth, depth_stride=flat.depth_stride,
-        search_steps=jf.search_steps)
-    new_nodes, new_fetched, new_alive, found, stay, mask = (
-        np.asarray(o) for o in out)
-    _, wave_nodes = np.nonzero(mask[:n])
-    i64 = np.int64
-    return {
-        "found": found[:n], "stay": stay[:n],
-        "nodes": new_nodes[:n].astype(i64),
-        "alive": new_alive[:n], "fetched": new_fetched[:n].astype(i64),
-        "wave_nodes": wave_nodes.astype(i64),
-    }
+    prof = obs.host_profile
+    with prof.span(obs.SPAN_HOST_WALK):
+        with prof.span(obs.SPAN_HOST_WALK_UPLOAD):
+            c = max_contexts or max(n, 1)
+            pad = c - n
+            alive = np.zeros(c, bool)
+            alive[:n] = True
+            host_in = [np.pad(np.asarray(a), (0, pad)).astype(np.int32)
+                       for a in (nodes, trees, fetched)] + [alive]
+            dev_in = [jnp.asarray(a) for a in host_in]
+        with prof.span(obs.SPAN_HOST_WALK_DISPATCH):
+            out = decision_walk_step(
+                jf.edge_item, jf.edge_child, jf.edge_first, jf.items,
+                jf.depth, jf.pre, jf.post, jf.n_children, jf.tree_start,
+                jf.tree_max_depth, jf.level_key, *dev_in,
+                item if 0 <= item < flat.item_stride else -1,
+                p_depth=p_depth, depth_stride=flat.depth_stride,
+                search_steps=jf.search_steps)
+        if prof.active:
+            # unprofiled, the first read-back below waits instead
+            with prof.span(obs.SPAN_HOST_WALK_WAIT):
+                jax.block_until_ready(out)
+        with prof.span(obs.SPAN_HOST_WALK_READBACK):
+            host_out = [np.asarray(o) for o in out]
+        with prof.span(obs.SPAN_HOST_WALK_UNPACK):
+            new_nodes, new_fetched, new_alive, found, stay, mask = host_out
+            _, wave_nodes = np.nonzero(mask[:n])
+            i64 = np.int64
+            state = {
+                "found": found[:n], "stay": stay[:n],
+                "nodes": new_nodes[:n].astype(i64),
+                "alive": new_alive[:n], "fetched": new_fetched[:n].astype(i64),
+                "wave_nodes": wave_nodes.astype(i64),
+            }
+    if prof.active:
+        prof.count(obs.METRIC_WALK_H2D_COPIES, len(host_in))
+        prof.count(obs.METRIC_WALK_H2D_BYTES, sum(a.nbytes for a in host_in))
+        prof.count(obs.METRIC_WALK_D2H_COPIES, len(host_out))
+        prof.count(obs.METRIC_WALK_D2H_BYTES, sum(a.nbytes for a in host_out))
+    return state

@@ -12,3 +12,8 @@ def record(self, metrics, shard, v):
     metrics.counter(kind).inc()               # violation: computed name
     self.tracer.span("demand", 0.0)           # violation: literal kind
     metrics.histogram("lat_" + str(shard)).record(v)   # violation
+
+
+def profile(self, obs, host_profile, shard):
+    with obs.host_profile.span("palp.decide"):   # violation: literal
+        host_profile.count(f"copies_{shard}", 1)  # violation: f-string

@@ -16,8 +16,14 @@ def record(self, metrics, v):
     metrics.histogram(obs.METRIC_READ_LATENCY).record(v)
 
 
+def profile(self, host_profile, n):
+    with obs.host_profile.span(obs.SPAN_HOST_DECIDE):
+        host_profile.count(obs.METRIC_WALK_H2D_BYTES, n)
+
+
 def unrelated(self, scheduler, game, now):
     # `.start(...)`/`.event(...)` on non-observability receivers stay
     # legal: the rule keys on tracer/metrics receivers only
     scheduler.start("warmup", now)
     game.event("goal", now)
+    scheduler.count("warmup", 1)

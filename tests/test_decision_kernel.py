@@ -15,6 +15,7 @@ from repro.core import (
     PTreeIndex,
     VectorizedPrefetchEngine,
 )
+from repro.core import obs
 from repro.kernels.decision_walk import ops as dw_ops
 from repro.kernels.decision_walk import ref as dw_ref
 
@@ -80,6 +81,115 @@ def test_decision_walk_interpret_escape_hatch(seed):
             np.testing.assert_array_equal(
                 np.asarray(jitted[key]), np.asarray(interp[key]),
                 err_msg=key)
+
+
+WALK_PHASES = (obs.SPAN_HOST_WALK_UPLOAD, obs.SPAN_HOST_WALK_DISPATCH,
+               obs.SPAN_HOST_WALK_WAIT, obs.SPAN_HOST_WALK_READBACK,
+               obs.SPAN_HOST_WALK_UNPACK)
+
+
+def _profiled(fn, *a, **kw):
+    """``fn(*a, **kw)`` under a fresh active host profile; returns
+    (result, profile) and puts the previous profile back."""
+    prof = obs.HostProfile()
+    old = obs.set_host_profile(prof)
+    try:
+        return fn(*a, **kw), prof
+    finally:
+        obs.set_host_profile(old)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_decision_walk_same_with_host_profile_on(seed, monkeypatch):
+    """The profile changes no output; each phase is one span per call,
+    inside ``palp.walk``; the counters count the four context uploads
+    and the six outputs read back, by their bytes."""
+    rng = np.random.default_rng(seed)
+    flat = random_index(seed, n_patterns=12).flatten()
+    if flat.n_nodes == 0 or not (flat.n_children > 0).any():
+        pytest.skip("degenerate forest")
+    jf = dw_ops.device_forest(flat)
+    out_bytes = []
+    step = dw_ops.decision_walk_step
+
+    def recording(*a, **kw):
+        out = step(*a, **kw)
+        out_bytes.append(sum(np.asarray(o).nbytes for o in out))
+        return out
+    monkeypatch.setattr(dw_ops, "decision_walk_step", recording)
+    for _ in range(4):
+        n = int(rng.integers(1, 9))
+        nodes, trees, fetched = live_states(flat, rng, n)
+        item = int(rng.integers(-2, flat.item_stride + 3))
+        args = (jf, flat, nodes, trees, fetched, item, 2)
+        off = dw_ops.decision_walk(*args, max_contexts=16)
+        on, prof = _profiled(dw_ops.decision_walk, *args, max_contexts=16)
+        for key in ("found", "stay", "nodes", "alive", "fetched",
+                    "wave_nodes"):
+            np.testing.assert_array_equal(off[key], on[key], err_msg=key)
+            assert off[key].dtype == on[key].dtype, key
+        assert prof.calls == {obs.SPAN_HOST_WALK: 1,
+                              **{p: 1 for p in WALK_PHASES}}
+        walk = prof.seconds[obs.SPAN_HOST_WALK]
+        phases = sum(prof.seconds[p] for p in WALK_PHASES)
+        assert prof.child_seconds[obs.SPAN_HOST_WALK] == phases <= walk
+        assert prof.counters == {
+            obs.METRIC_WALK_H2D_COPIES: 4,
+            obs.METRIC_WALK_H2D_BYTES: 16 * (3 * 4 + 1),  # 3 int32, 1 bool
+            obs.METRIC_WALK_D2H_COPIES: 6,
+            obs.METRIC_WALK_D2H_BYTES: out_bytes[-1],
+        }
+    # the dense (contexts, nodes) wave mask is most of what comes back
+    assert out_bytes[-1] == 16 * (2 * 4 + 3) + 16 * flat.n_nodes
+
+
+def test_decision_walk_escape_paths_record_nothing():
+    flat = random_index(0, n_patterns=12).flatten()
+    jf = dw_ops.device_forest(flat)
+    nodes, trees, fetched = live_states(flat, np.random.default_rng(0), 3)
+    _, prof = _profiled(dw_ops.decision_walk, jf, flat, nodes, trees,
+                        fetched, 1, 2, max_contexts=16, interpret=True)
+    assert not prof.calls and not prof.counters
+    empty = PTreeIndex.build([]).flatten()
+    z = np.empty(0, np.int64)
+    _, prof = _profiled(dw_ops.decision_walk, dw_ops.device_forest(empty),
+                        empty, z, z, z, 3, 2, max_contexts=4)
+    assert not prof.calls and not prof.counters
+
+
+def test_decision_walk_spans_nest_in_a_profiler_trace(tmp_path):
+    """The profile's spans are profiler annotations: a trace holds each
+    ``palp.walk`` with its five phases inside it, in order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    flat = random_index(0, n_patterns=12).flatten()
+    jf = dw_ops.device_forest(flat)
+    nodes, trees, fetched = live_states(flat, np.random.default_rng(1), 4)
+    args = (jf, flat, nodes, trees, fetched, 1, 2)
+    dw_ops.decision_walk(*args, max_contexts=16)          # compile first
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            _profiled(dw_ops.decision_walk, *args, max_contexts=16)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    names = {obs.SPAN_HOST_WALK, *WALK_PHASES}
+    spans = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                   for plane in ProfileData.from_file(str(path)).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name in names)
+    walks = [sp for sp in spans if sp[2] == obs.SPAN_HOST_WALK]
+    assert len(walks) == 3
+    for w0, w1, _ in walks:
+        inside = [sp for sp in spans
+                  if sp[2] != obs.SPAN_HOST_WALK and w0 <= sp[0]
+                  and sp[1] <= w1]
+        assert [name for _, _, name in inside] == list(WALK_PHASES)
+        for (_, end, _), (start, _, _) in zip(inside, inside[1:]):
+            assert end <= start
 
 
 #: a paper-scale item vocabulary: with ~10^3 nodes, node id × item_stride

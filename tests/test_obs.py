@@ -25,23 +25,39 @@ from repro.core import (
     ShardedDKVStore,
     SimulatedDKVStore,
 )
+from repro.core import obs
 from repro.core.obs import (
     EVENT_RETRY,
     METRIC_OPS,
     METRIC_READ_LATENCY,
     METRIC_STALE_READS,
+    METRIC_WALK_D2H_BYTES,
+    METRIC_WALK_D2H_COPIES,
+    METRIC_WALK_H2D_BYTES,
+    METRIC_WALK_H2D_COPIES,
+    NULL_HOST_PROFILE,
     NULL_SPAN,
     NULL_TRACER,
+    REGISTERED_NAMES,
+    SPAN_HOST_DECIDE,
+    SPAN_HOST_WALK,
+    SPAN_HOST_WALK_DISPATCH,
+    SPAN_HOST_WALK_READBACK,
+    SPAN_HOST_WALK_UNPACK,
+    SPAN_HOST_WALK_UPLOAD,
+    SPAN_HOST_WALK_WAIT,
     SPAN_OP,
     SPAN_ROUTE,
     SPAN_RPC,
     SPAN_SERVICE,
+    HostProfile,
     Histogram,
     MetricsRegistry,
     Tracer,
     critical_path,
     latency_percentiles,
     percentile,
+    set_host_profile,
     span_kind_breakdown,
 )
 
@@ -225,6 +241,146 @@ class TestTracer:
         assert bd[SPAN_OP]["count"] == 1      # events excluded
         assert [h["kind"] for h in critical_path(trace)] == [
             SPAN_OP, SPAN_ROUTE]
+
+
+# ---------------------------------------------------------------------------
+# Host profile (host clock, off by default)
+# ---------------------------------------------------------------------------
+
+
+def _fake_clock(step: float = 1.0):
+    """A clock that moves ``step`` seconds on every read."""
+    now = [0.0]
+
+    def clock() -> float:
+        now[0] += step
+        return now[0]
+    return clock
+
+
+class TestHostProfile:
+    def test_null_profile_records_nothing_and_reads_no_clock(
+            self, monkeypatch):
+        client = _client_with_mined_chains()     # mining reads the clock
+
+        def no_clock() -> float:
+            raise AssertionError("the null profile read the host clock")
+        monkeypatch.setattr(obs, "host_clock", no_clock)
+        assert obs.host_profile is NULL_HOST_PROFILE
+        assert not NULL_HOST_PROFILE.active
+        ctx = NULL_HOST_PROFILE.span(SPAN_HOST_DECIDE)
+        assert NULL_HOST_PROFILE.span(SPAN_HOST_WALK) is ctx   # prebuilt
+        with ctx:
+            pass
+        assert NULL_HOST_PROFILE.count(METRIC_WALK_H2D_BYTES, 7) is None
+        # served reads open palp.decide around every engine call
+        for s in ([f"k{j}" for j in range(i, i + 5)]
+                  for i in range(0, 50, 5)):
+            for k in s:
+                client.read(k)
+            client.end_session()
+        assert client.cache.stats.prefetch_hits > 0
+        assert not vars(NULL_HOST_PROFILE)
+
+    def test_obs_imports_without_jax(self):
+        import os
+        import subprocess
+        import sys
+        from pathlib import Path
+
+        src = Path(__file__).resolve().parents[1] / "src"
+        code = ("import sys; sys.modules['jax'] = None\n"
+                "from repro.core import obs\n"
+                "assert obs.host_profile is obs.NULL_HOST_PROFILE\n"
+                "with obs.NULL_HOST_PROFILE.span(obs.SPAN_HOST_WALK):\n"
+                "    pass\n"
+                "obs.NULL_HOST_PROFILE.count(obs.METRIC_WALK_D2H_BYTES, 1)\n")
+        r = subprocess.run([sys.executable, "-c", code],
+                           env=dict(os.environ, PYTHONPATH=str(src)),
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr
+
+    def test_one_switch_returns_the_replaced_profile(self):
+        p = HostProfile()
+        old = set_host_profile(p)
+        try:
+            assert old is NULL_HOST_PROFILE and obs.host_profile is p
+        finally:
+            assert set_host_profile(old) is p
+        assert obs.host_profile is NULL_HOST_PROFILE
+
+    def test_names_are_registered_and_prefixed(self):
+        names = [SPAN_HOST_DECIDE, SPAN_HOST_WALK, SPAN_HOST_WALK_UPLOAD,
+                 SPAN_HOST_WALK_DISPATCH, SPAN_HOST_WALK_WAIT,
+                 SPAN_HOST_WALK_READBACK, SPAN_HOST_WALK_UNPACK,
+                 METRIC_WALK_H2D_COPIES, METRIC_WALK_H2D_BYTES,
+                 METRIC_WALK_D2H_COPIES, METRIC_WALK_D2H_BYTES]
+        assert len(set(names)) == len(names)
+        assert set(names) <= REGISTERED_NAMES
+        assert all(n.startswith("palp.") for n in names)
+
+    def test_active_profile_counts_calls_seconds_and_child_time(self):
+        p = HostProfile(clock=_fake_clock())
+        assert p.active
+        for _ in range(2):
+            # clock reads: outer 1 .. 8; upload 2-3, dispatch 4-5,
+            # readback 6-7: 7 s outer, 3 x 1 s children
+            with p.span(SPAN_HOST_WALK):
+                with p.span(SPAN_HOST_WALK_UPLOAD):
+                    pass
+                with p.span(SPAN_HOST_WALK_DISPATCH):
+                    pass
+                with p.span(SPAN_HOST_WALK_READBACK):
+                    pass
+            p.count(METRIC_WALK_D2H_BYTES, 100)
+        p.count(METRIC_WALK_D2H_COPIES)
+        assert p.calls == {SPAN_HOST_WALK: 2, SPAN_HOST_WALK_UPLOAD: 2,
+                           SPAN_HOST_WALK_DISPATCH: 2,
+                           SPAN_HOST_WALK_READBACK: 2}
+        assert p.seconds[SPAN_HOST_WALK] == 14.0
+        assert p.seconds[SPAN_HOST_WALK_UPLOAD] == 2.0
+        assert p.child_seconds[SPAN_HOST_WALK] == 6.0
+        assert p.child_seconds[SPAN_HOST_WALK_UPLOAD] == 0.0
+        assert p.self_seconds(SPAN_HOST_WALK) == 8.0
+        assert p.self_seconds(SPAN_HOST_DECIDE) == 0.0      # never opened
+        assert p.counters == {METRIC_WALK_D2H_BYTES: 200,
+                              METRIC_WALK_D2H_COPIES: 1}
+
+    def test_nesting_books_children_to_their_direct_parent(self):
+        p = HostProfile(clock=_fake_clock())
+        with p.span(SPAN_HOST_DECIDE):                  # 1 .. 8
+            with p.span(SPAN_HOST_WALK):                # 2 .. 7
+                with p.span(SPAN_HOST_WALK_WAIT):       # 3 .. 4
+                    pass
+                with pytest.raises(KeyError):
+                    with p.span(SPAN_HOST_WALK_UNPACK):  # 5 .. 6
+                        raise KeyError("an exception still closes it")
+        assert p.seconds == {SPAN_HOST_DECIDE: 7.0, SPAN_HOST_WALK: 5.0,
+                             SPAN_HOST_WALK_WAIT: 1.0,
+                             SPAN_HOST_WALK_UNPACK: 1.0}
+        assert p.child_seconds[SPAN_HOST_DECIDE] == 5.0  # walk only
+        assert p.child_seconds[SPAN_HOST_WALK] == 2.0
+        assert p.self_seconds(SPAN_HOST_DECIDE) == 2.0
+        assert not p._stack
+
+    def test_served_path_opens_one_decide_span_per_engine_call(self):
+        client = _client_with_mined_chains()
+        calls = [0]
+        on_request = client.engine.on_request
+
+        def counted(iid):
+            calls[0] += 1
+            return on_request(iid)
+        client.engine.on_request = counted
+        p = HostProfile(clock=_fake_clock())
+        old = set_host_profile(p)
+        try:
+            for k in ("k0", "k1", "k2"):
+                client.read(k)
+        finally:
+            set_host_profile(old)
+        assert calls[0] > 0
+        assert p.calls == {SPAN_HOST_DECIDE: calls[0]}
 
 
 # ---------------------------------------------------------------------------

@@ -158,3 +158,45 @@ def test_online_mining_adapts_to_new_patterns():
         for node in tree.root.level_order():
             in_trees.add(node.item)
     assert ids & in_trees
+
+
+@pytest.mark.parametrize("use_dynamic_minsup", [True, False])
+def test_mining_wall_time_covers_the_whole_round(monkeypatch,
+                                                 use_dynamic_minsup):
+    """A round's host time runs from ``mine_now``'s entry, column round
+    included, to the new trees' install; ``rebuild_wall_time`` is the
+    install of both metastores.  A fake clock that only mining and tree
+    building move makes both exact."""
+    from repro.core import PTreeIndex, obs
+    from repro.core import palpatine as pal
+
+    now = [0.0]
+    monkeypatch.setattr(obs, "host_clock", lambda: now[0])
+    mined, built = [], []
+
+    def timed(fn, cost, log):
+        def wrapped(*a, **kw):
+            now[0] += cost
+            log.append(cost)
+            return fn(*a, **kw)
+        return wrapped
+
+    client = PalpatineClient(build_store(), PalpatineConfig(
+        mining=MiningParams(minsup=0.02, min_len=3, max_len=10, maxgap=1),
+        column_mining=True))
+    for sess in workload(np.random.default_rng(3), 120):
+        for key in sess:
+            client.read(key)
+        client.end_session()
+    name = "mine_dynamic_minsup" if use_dynamic_minsup else "mine"
+    monkeypatch.setattr(pal, name, timed(getattr(pal, name), 10.0, mined))
+    monkeypatch.setattr(PTreeIndex, "build",
+                        staticmethod(timed(PTreeIndex.build, 1.0, built)))
+    for _ in range(2):
+        client.mine_now(use_dynamic_minsup)
+    assert client.col_metastore is not None and len(client.metastore) > 0
+    assert len(mined) == 4 and len(built) == 4    # column + main, twice
+    assert client.mining_runs == 2
+    assert client.mining_wall_time == sum(mined) + sum(built) == 44.0
+    assert client.rebuild_wall_time == sum(built) == 4.0
+    assert client.rebuild_wall_time <= client.mining_wall_time

@@ -69,7 +69,7 @@ def test_positive_counts_and_lines_are_stable():
     expect = {"PALP001": 6, "PALP002": 6, "PALP003": 6,
               "PALP101": 3, "PALP102": 2, "PALP103": 2, "PALP104": 2,
               "PALP201": 3, "PALP202": 3, "PALP203": 2,
-              "PALP301": 5}
+              "PALP301": 7}
     for code, n in sorted(expect.items()):
         diags = [d for d in run_rule(code, fixture(f"{code.lower()}_bad.py"))
                  if d.code == code]

@@ -22,14 +22,16 @@ from ..diagnostics import Diagnostic
 from ..registry import FileContext, Rule, register
 
 #: receiver names an observability call is recognized by (by convention
-#: tracers are bound to ``tr``/``tracer``/``<obj>.tracer`` and
-#: registries to ``metrics``/``registry``/``<obj>.metrics``)
-_RECEIVERS = {"tr", "tracer", "metrics", "registry"}
-_RECEIVER_ATTRS = {"tracer", "metrics"}
+#: tracers are bound to ``tr``/``tracer``/``<obj>.tracer``, registries
+#: to ``metrics``/``registry``/``<obj>.metrics`` and the host profile to
+#: ``host_profile``/``obs.host_profile``)
+_RECEIVERS = {"tr", "tracer", "metrics", "registry", "host_profile"}
+_RECEIVER_ATTRS = {"tracer", "metrics", "host_profile"}
 
 #: the name-taking observability methods (first positional argument is
 #: a span kind, event name, or metric name)
-_METHODS = {"start", "span", "event", "counter", "gauge", "histogram"}
+_METHODS = {"start", "span", "event", "counter", "gauge", "histogram",
+            "count"}
 
 _PREFIXES = ("SPAN_", "EVENT_", "METRIC_")
 
