@@ -18,6 +18,15 @@ item — a probability-matrix walk over the flattened pattern forest:
   ``fetch_top_n`` initial waves (stable lexicographic (cum_prob desc,
   depth asc, level-order asc) pick, re-emitted (depth asc, cum desc)).
 
+Each call moves one array each way.  In: one int32 vector of 3·C + 2,
+``[nodes | trees | fetched | n, item]`` with each context column padded
+to C; the step rebuilds ``alive`` as the first ``n`` rows.  Out: one
+int32 (C, 5 + W) array, W = ceil(N / 32): columns 0-4 are ``new_nodes``,
+``new_fetched``, ``new_alive``, ``found`` and ``stay``; columns 5.. hold
+the wave mask bit-packed along N, little-endian — node ``32·w + b`` is
+bit ``b`` of word ``w`` (each uint32 word bitcast to int32), and the
+bits past N in the last word are 0.
+
 Shapes are static per mining generation (N nodes, E edges, C =
 ``max_contexts``), so each generation compiles once.  The numpy
 reference in :mod:`.ref` delegates to the core engine's pure step
@@ -34,23 +43,30 @@ import jax.numpy as jnp
 
 __all__ = ["decision_walk_step", "top_k_frontier"]
 
+#: wave-mask bits per packed word
+WORD_BITS = 32
+
 
 @partial(jax.jit,
          static_argnames=("p_depth", "depth_stride", "search_steps"))
 def decision_walk_step(edge_item, edge_child, edge_first, items, depth,
                        pre, post, n_children, tree_start, tree_max_depth,
-                       level_key, nodes, trees, fetched, alive, item,
-                       *, p_depth: int, depth_stride: int,
+                       level_key, ctx, *, p_depth: int, depth_stride: int,
                        search_steps: int):
-    """Advance C (padded) contexts by ``item``; returns the new context
-    state plus the dense (C, N) wave mask.
+    """Advance C (padded) contexts by ``item``; returns the packed
+    (C, 5 + W) int32 output laid out in the module docstring.
 
-    ``item`` is -1 when it lies outside the forest's vocabulary (it then
-    matches no edge and no root).  ``search_steps`` must be at least the
-    bit length of the largest ``n_children``.  Dead/padding rows carry
-    ``alive=False`` and never match, emit, or resurrect — zero-padding is
-    decision-neutral, mirroring the support-neutral padding contract of
+    ``ctx`` is the packed (3·C + 2,) input.  Its ``item`` is -1 when the
+    item lies outside the forest's vocabulary (it then matches no edge
+    and no root).  ``search_steps`` must be at least the bit length of
+    the largest ``n_children``.  Rows at and past ``n`` are dead: they
+    never match, emit, or resurrect — zero-padding is decision-neutral,
+    mirroring the support-neutral padding contract of
     ``frontier_join_support``."""
+    c = (ctx.shape[0] - 2) // 3
+    nodes, trees, fetched = ctx[:c], ctx[c:2 * c], ctx[2 * c:3 * c]
+    alive = jnp.arange(c) < ctx[3 * c]
+    item = ctx[3 * c + 1]
     last = edge_item.shape[0] - 1
     first = edge_first[nodes]
     end = first + n_children[nodes]
@@ -80,7 +96,17 @@ def decision_walk_step(edge_item, edge_child, edge_first, items, depth,
     sub = ((pre[None, :] >= pre[new_nodes][:, None])
            & (pre[None, :] < post[new_nodes][:, None]))
     wave_mask = band & sub & emit[:, None]
-    return new_nodes, new_fetched, new_alive, found, stay, wave_mask
+    n = wave_mask.shape[1]
+    words = -(-n // WORD_BITS)
+    bits = jnp.pad(wave_mask, ((0, 0), (0, words * WORD_BITS - n)))
+    bits = bits.reshape(c, words, WORD_BITS).astype(jnp.uint32)
+    # distinct powers of two: the sum is their bitwise or
+    packed = (bits << jnp.arange(WORD_BITS, dtype=jnp.uint32)).sum(
+        axis=-1, dtype=jnp.uint32)
+    cols = jnp.stack([new_nodes, new_fetched, new_alive, found, stay],
+                     axis=1).astype(jnp.int32)
+    return jnp.concatenate(
+        [cols, jax.lax.bitcast_convert_type(packed, jnp.int32)], axis=1)
 
 
 @partial(jax.jit, static_argnames=("k",))

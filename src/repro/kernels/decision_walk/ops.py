@@ -2,13 +2,16 @@
 
 ``device_forest`` ships one mining generation's :class:`FlatForest` to
 the device as int32 arrays (the device runs with x64 off) and refuses a
-forest whose ids would not fit; ``decision_walk`` pads the live context
-state to the engine's ``max_contexts`` — keeping every shape static per
-generation, one compile each — runs the jitted step, and unpads back to
-the compact numpy state dict the core engine consumes.  Under an active
-host profile (:mod:`repro.core.obs`) a jitted call is the span
-``palp.walk``, split into upload, dispatch, wait, readback and unpack,
-with its copies each way and their bytes counted.
+forest whose ids would not fit; ``decision_walk`` packs the live context
+state, padded to the engine's ``max_contexts`` — keeping every shape
+static per generation, one compile each — with the live count and the
+item into one int32 vector, uploads it with one copy, runs the jitted
+step, reads its one packed output back with one copy, and unpacks that
+(the layout is in :mod:`.decision_walk`'s docstring) to the compact
+numpy state dict the core engine consumes.  Under an active host
+profile (:mod:`repro.core.obs`) a jitted call is the span ``palp.walk``,
+split into upload, dispatch, wait, readback and unpack, with its copies
+each way and their bytes counted.
 """
 
 from __future__ import annotations
@@ -20,7 +23,7 @@ import jax.numpy as jnp
 from repro.core import obs
 
 from . import ref as _ref
-from .decision_walk import decision_walk_step, top_k_frontier
+from .decision_walk import WORD_BITS, decision_walk_step, top_k_frontier
 
 __all__ = ["device_forest", "decision_walk", "top_k_frontier"]
 
@@ -74,6 +77,17 @@ def device_forest(flat) -> DeviceForest:
     return DeviceForest(flat)
 
 
+def _pad_and_pack(c: int, nodes, trees, fetched, item: int) -> np.ndarray:
+    """The walk's one upload: ``nodes | trees | fetched``, each padded
+    with zeros to ``c`` rows, then the live count and ``item``."""
+    n = len(nodes)
+    ctx = np.zeros(3 * c + 2, np.int32)
+    for k, a in enumerate((nodes, trees, fetched)):
+        ctx[k * c:k * c + n] = a
+    ctx[3 * c:] = n, item
+    return ctx
+
+
 def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
                   item: int, p_depth: int,
                   max_contexts: int | None = None,
@@ -82,7 +96,7 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
 
     Returns the same state dict as :func:`repro.core.decision.
     advance_step`, plus the already-selected ``wave_nodes`` (row-major
-    nonzeros of the dense wave mask = the scalar engine's context-major,
+    nonzeros of the wave mask = the scalar engine's context-major,
     level-ordered emission).
 
     ``interpret=True`` is the escape hatch: it routes through the pure
@@ -105,40 +119,46 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
     prof = obs.host_profile
     with prof.span(obs.SPAN_HOST_WALK):
         with prof.span(obs.SPAN_HOST_WALK_UPLOAD):
-            c = max_contexts or max(n, 1)
-            pad = c - n
-            alive = np.zeros(c, bool)
-            alive[:n] = True
-            host_in = [np.pad(np.asarray(a), (0, pad)).astype(np.int32)
-                       for a in (nodes, trees, fetched)] + [alive]
-            dev_in = [jnp.asarray(a) for a in host_in]
+            ctx = _pad_and_pack(max_contexts or max(n, 1), nodes, trees,
+                                fetched,
+                                item if 0 <= item < flat.item_stride else -1)
+            dev_ctx = jax.device_put(ctx)
         with prof.span(obs.SPAN_HOST_WALK_DISPATCH):
             out = decision_walk_step(
                 jf.edge_item, jf.edge_child, jf.edge_first, jf.items,
                 jf.depth, jf.pre, jf.post, jf.n_children, jf.tree_start,
-                jf.tree_max_depth, jf.level_key, *dev_in,
-                item if 0 <= item < flat.item_stride else -1,
+                jf.tree_max_depth, jf.level_key, dev_ctx,
                 p_depth=p_depth, depth_stride=flat.depth_stride,
                 search_steps=jf.search_steps)
         if prof.active:
-            # unprofiled, the first read-back below waits instead
+            # unprofiled, the read-back below waits instead
             with prof.span(obs.SPAN_HOST_WALK_WAIT):
                 jax.block_until_ready(out)
         with prof.span(obs.SPAN_HOST_WALK_READBACK):
-            host_out = [np.asarray(o) for o in out]
+            host_out = np.asarray(out)
         with prof.span(obs.SPAN_HOST_WALK_UNPACK):
-            new_nodes, new_fetched, new_alive, found, stay, mask = host_out
-            _, wave_nodes = np.nonzero(mask[:n])
+            live = host_out[:n]
+            words = live[:, 5:]
+            # expand only the words with bits set: (context, word, bit)
+            # order is the wave's row-major order, and the device leaves
+            # the bits past N at 0
+            _, w = np.nonzero(words)
+            le_bytes = words[words != 0].astype("<i4").view(np.uint8)
+            i, b = np.nonzero(np.unpackbits(le_bytes.reshape(-1, 4), axis=1,
+                                            bitorder="little"))
+            wave_nodes = w[i] * WORD_BITS + b
             i64 = np.int64
             state = {
-                "found": found[:n], "stay": stay[:n],
-                "nodes": new_nodes[:n].astype(i64),
-                "alive": new_alive[:n], "fetched": new_fetched[:n].astype(i64),
+                "found": live[:, 3].astype(bool),
+                "stay": live[:, 4].astype(bool),
+                "nodes": live[:, 0].astype(i64),
+                "alive": live[:, 2].astype(bool),
+                "fetched": live[:, 1].astype(i64),
                 "wave_nodes": wave_nodes.astype(i64),
             }
     if prof.active:
-        prof.count(obs.METRIC_WALK_H2D_COPIES, len(host_in))
-        prof.count(obs.METRIC_WALK_H2D_BYTES, sum(a.nbytes for a in host_in))
-        prof.count(obs.METRIC_WALK_D2H_COPIES, len(host_out))
-        prof.count(obs.METRIC_WALK_D2H_BYTES, sum(a.nbytes for a in host_out))
+        prof.count(obs.METRIC_WALK_H2D_COPIES, 1)
+        prof.count(obs.METRIC_WALK_H2D_BYTES, ctx.nbytes)
+        prof.count(obs.METRIC_WALK_D2H_COPIES, 1)
+        prof.count(obs.METRIC_WALK_D2H_BYTES, host_out.nbytes)
     return state

@@ -99,11 +99,17 @@ def _profiled(fn, *a, **kw):
         obs.set_host_profile(old)
 
 
+def packed_bytes(c, n_nodes):
+    """Bytes of the walk's packed output: per context five state columns
+    and ceil(n_nodes / 32) wave words, all int32."""
+    return c * (5 + -(-n_nodes // 32)) * 4
+
+
 @pytest.mark.parametrize("seed", range(3))
 def test_decision_walk_same_with_host_profile_on(seed, monkeypatch):
     """The profile changes no output; each phase is one span per call,
-    inside ``palp.walk``; the counters count the four context uploads
-    and the six outputs read back, by their bytes."""
+    inside ``palp.walk``; the counters count the one packed upload and
+    the one packed read-back, by their bytes."""
     rng = np.random.default_rng(seed)
     flat = random_index(seed, n_patterns=12).flatten()
     if flat.n_nodes == 0 or not (flat.n_children > 0).any():
@@ -114,7 +120,7 @@ def test_decision_walk_same_with_host_profile_on(seed, monkeypatch):
 
     def recording(*a, **kw):
         out = step(*a, **kw)
-        out_bytes.append(sum(np.asarray(o).nbytes for o in out))
+        out_bytes.append(np.asarray(out).nbytes)
         return out
     monkeypatch.setattr(dw_ops, "decision_walk_step", recording)
     for _ in range(4):
@@ -134,13 +140,63 @@ def test_decision_walk_same_with_host_profile_on(seed, monkeypatch):
         phases = sum(prof.seconds[p] for p in WALK_PHASES)
         assert prof.child_seconds[obs.SPAN_HOST_WALK] == phases <= walk
         assert prof.counters == {
-            obs.METRIC_WALK_H2D_COPIES: 4,
-            obs.METRIC_WALK_H2D_BYTES: 16 * (3 * 4 + 1),  # 3 int32, 1 bool
-            obs.METRIC_WALK_D2H_COPIES: 6,
-            obs.METRIC_WALK_D2H_BYTES: out_bytes[-1],
+            obs.METRIC_WALK_H2D_COPIES: 1,
+            obs.METRIC_WALK_H2D_BYTES: (3 * 16 + 2) * 4,
+            obs.METRIC_WALK_D2H_COPIES: 1,
+            obs.METRIC_WALK_D2H_BYTES: packed_bytes(16, flat.n_nodes),
         }
-    # the dense (contexts, nodes) wave mask is most of what comes back
-    assert out_bytes[-1] == 16 * (2 * 4 + 3) + 16 * flat.n_nodes
+    # the step's one output is the bit-packed array, nothing more
+    assert out_bytes[-1] == packed_bytes(16, flat.n_nodes)
+
+
+def branching_forest(n_nodes):
+    """One tree of exactly ``n_nodes`` (>= 2): root 0, its child 1, and
+    the leaves 2 .. n_nodes - 1 under 1, so stepping a root context by 1
+    emits a wave over every node but the root."""
+    pats = ([Pattern((0, 1), 3)] if n_nodes == 2 else
+            [Pattern((0, 1, k), 3) for k in range(2, n_nodes)])
+    flat = PTreeIndex.build(pats).flatten()
+    assert flat.n_nodes == n_nodes
+    return flat
+
+
+#: items: none (-1), past the vocabulary, and the one that emits a wave
+EDGE_ITEMS = {"none": lambda flat: -1,
+              "outside": lambda flat: flat.item_stride + 5,
+              "match": lambda flat: 1}
+
+
+@pytest.mark.parametrize("item_kind", list(EDGE_ITEMS))
+@pytest.mark.parametrize("live", ["one", "all"])
+@pytest.mark.parametrize("n_nodes", [2, 31, 32, 33, 64, 65])
+def test_decision_walk_bit_packing_edges(n_nodes, live, item_kind):
+    """The packed read-back at the word edges of the wave mask: forests
+    one node short of, at and past 32 and 64 nodes (2 is the smallest a
+    pattern builds), one live context or all C, and every outcome of the
+    item.  Every output equals the reference's, dtype included, and the
+    wave comes back row-major."""
+    c = 16
+    flat = branching_forest(n_nodes)
+    jf = dw_ops.device_forest(flat)
+    rng = np.random.default_rng(n_nodes)
+    n = 1 if live == "one" else c
+    nodes, trees, fetched = live_states(flat, rng, n)
+    nodes[0], fetched[0] = 0, 0         # a root context, nothing fetched
+    item = EDGE_ITEMS[item_kind](flat)
+    a = dw_ops.decision_walk(jf, flat, nodes, trees, fetched, item, 2,
+                             max_contexts=c)
+    b = dw_ref.decision_walk_ref(flat, nodes, trees, fetched, item, 2)
+    for key in ("found", "stay", "nodes", "alive", "fetched",
+                "wave_nodes"):
+        np.testing.assert_array_equal(a[key], b[key], err_msg=key)
+        assert a[key].dtype == b[key].dtype, key
+    if item_kind == "match":
+        # context 0's wave is every node but the root, the last word's
+        # last node included; later contexts' waves follow it
+        np.testing.assert_array_equal(a["wave_nodes"][:n_nodes - 1],
+                                      np.arange(1, n_nodes))
+    else:
+        assert a["wave_nodes"].size == 0
 
 
 def test_decision_walk_escape_paths_record_nothing():
