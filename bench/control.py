@@ -46,7 +46,7 @@ def control_run(spec: dict, seed: int, calls: int,
     reads = harness.replay(ref, ops)
     program = {"reads": reads, "targets": ref.targets,
                "col_targets": ref.col_targets, "rounds": ref.rounds,
-               "stats": ref.stats}
+               "stats": ref.stats, "window_programs": 0}
     return harness.compare(program, ops, work["data"], config, seed)
 
 

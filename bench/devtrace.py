@@ -5,22 +5,26 @@ The device's operations are the events of the ``XLA Ops`` line of each
 ``/device:TPU:<n>`` plane.  The host's activity comes from the
 ``jax.profiler.TraceAnnotation`` spans the harness writes (``serve``
 around each client call, ``decide`` around each decision engine's
-call); the traced window runs from the first ``serve`` span's start to
-the last one's end.
+call) and those of the program's host profile (``palp.decide``,
+``palp.walk`` and its phases, ``palp.mine`` and whatever else it names
+``palp.*``); the traced window runs from the first ``serve`` span's
+start to the last one's end.
 """
 
 from __future__ import annotations
 
-import bisect
+import heapq
 import re
 import shutil
 from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
-#: host spans, innermost first: a gap is put down to the innermost span
-#: of these that covers its midpoint
+#: the harness's host spans; with the program's (``PROGRAM_SPANS``) they
+#: are what an idle gap is put down to: the innermost of them that
+#: covers the gap's midpoint, or ``harness`` where none does
 HOST_SPANS = ("decide", "serve")
+PROGRAM_SPANS = "palp."
 TOP = 10
 
 
@@ -50,8 +54,31 @@ def load(tdir: Path) -> tuple[dict, list]:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 host.extend((e.name, e.start_ns, e.duration_ns)
-                            for e in line.events if e.name in HOST_SPANS)
+                            for e in line.events if host_span(e.name))
     return device, host
+
+
+def host_span(name: str) -> bool:
+    return name in HOST_SPANS or name.startswith(PROGRAM_SPANS)
+
+
+def innermost(spans: list, points: list) -> list:
+    """For each point, in ascending order, the name of the innermost span
+    that covers it (the one that started last; of two that started
+    together, the shorter), or None.  A span is (name, start, end)."""
+    spans = sorted(spans, key=lambda sp: sp[1])
+    heap: list = []
+    out = []
+    i = 0
+    for p in points:
+        while i < len(spans) and spans[i][1] <= p:
+            name, s, e = spans[i]
+            heapq.heappush(heap, (-s, e - s, e, name))
+            i += 1
+        while heap and heap[0][2] <= p:
+            heapq.heappop(heap)
+        out.append(heap[0][3] if heap else None)
+    return out
 
 
 def merge(intervals: list) -> list:
@@ -91,19 +118,13 @@ def reduce_events(device: dict, host: list) -> dict:
         gaps += [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
                  if edges[i + 1] > edges[i]]
     n_dev = max(1, len(device))
-    spans = {name: sorted((s, s + d) for n, s, d in host if n == name)
-             for name in HOST_SPANS}
-    starts = {name: [s for s, _ in iv] for name, iv in spans.items()}
+    gaps.sort(key=lambda g: g[0] + g[1])
+    who = innermost([(n, s, s + d) for n, s, d in host if host_span(n)],
+                    [(a + b) / 2 for a, b in gaps])
     idle: dict = {}
-    for a, b in gaps:
-        mid = (a + b) / 2
-        who = "harness"
-        for name in HOST_SPANS:
-            i = bisect.bisect_right(starts[name], mid) - 1
-            if i >= 0 and spans[name][i][1] > mid:
-                who = name
-                break
-        idle[who] = idle.get(who, 0.0) + (b - a) * 1e-9 / n_dev
+    for (a, b), name in zip(gaps, who):
+        name = name or "harness"
+        idle[name] = idle.get(name, 0.0) + (b - a) * 1e-9 / n_dev
     top = sorted(ops.items(), key=lambda kv: -kv[1])[:TOP]
     # an HLO op's event is named by its whole instruction; keep its name
     top = [(k.split(" = ", 1)[0].lstrip("%"), v) for k, v in top]

@@ -11,6 +11,7 @@ per-layer metric.  The program under test is driven only through
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import gc
 import importlib.util
@@ -30,6 +31,12 @@ ROOT = BENCH.parent
 DEVICE_SELECTORS = {"decision_backend": "jax", "use_kernel": True}
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: the events of JAX making a program (jax 0.9's names): tracing a
+#: function to a jaxpr, compiling it for the backend, and loading it from
+#: the persistent compilation cache.  A program first used in the window
+#: records one or more of them there, whether the cache holds it or not.
+PROGRAM_EVENTS = ("/jax/core/compile/jaxpr_trace_duration", _COMPILE_EVENT,
+                  "/jax/compilation_cache/cache_retrieval_time_sec")
 
 
 def load_module(path: Path):
@@ -107,7 +114,8 @@ class Run:
         self.trace = trace
         self.in_window = False
         self.window_calls = 0
-        self.compiles = []                # (seconds, in window)
+        self.compiles = []                # seconds of each backend compile
+        self.window_programs = collections.Counter()  # PROGRAM_EVENTS
         self.state: dict = {}             # per-reader scratch
         self.trace_data = None            # reduced profiler trace
         self._patches: list = []
@@ -150,7 +158,9 @@ class Run:
 
     def on_event(self, event: str, secs: float, **_) -> None:
         if event == _COMPILE_EVENT:
-            self.compiles.append((secs, self.in_window))
+            self.compiles.append(secs)
+        if self.in_window and event in PROGRAM_EVENTS:
+            self.window_programs[event] += 1
 
 
 def _annotate(name: str, fn):
@@ -216,7 +226,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
                 run.patch(eng, "on_request",
                           lambda f: _annotate("decide", f))
         setup_compiles = len(run.compiles)
-        setup_compile_s = sum(s for s, _ in run.compiles)
+        setup_compile_s = sum(run.compiles)
         rss_setup = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
 
         import devtrace as trace_mod
@@ -239,7 +249,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
             run.trace_data = trace_mod.reduce(tdir)
             device["busy_s"] = run.trace_data["busy_s"]
             device["window_s"] = run.trace_data["window_s"]
-        window_compiles = sum(1 for _, w in run.compiles if w)
+        window_programs = sum(run.window_programs.values())
         run.restore()
 
         n = len(lat)
@@ -263,14 +273,17 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool,
         say("set-up s: " + ", ".join(f"{k} {v}" for k, v in setup.items())
             + f"; total {setup_s}")
         say(f"set-up compiles: {setup_compiles} ({setup_compile_s} s); "
-            f"compiles inside the window: {window_compiles}")
+            f"programs traced, compiled or loaded inside the window: "
+            f"{window_programs} {dict(run.window_programs)}")
         wraps = rec.sessions_in_window // (len(work["window"])
                                            - mix["warm_sessions"])
         say(f"window: {n} calls in {window_s} s, {rec.sessions_in_window} "
-            f"sessions; traffic wrapped {wraps} times")
+            f"sessions; traffic wrapped {wraps} times; slowest call "
+            f"{max(lat, default=0.0)} s")
         say(f"host peak RSS after set-up: {rss_setup} KiB")
 
         program = rec.outputs()
+        program["window_programs"] = window_programs
         ops = rec.ops
         del client, store, rec, run.client
         gc.collect()
@@ -446,8 +459,10 @@ def replay(client, steps, ops: list | None = None) -> list:
 
 def compare(program: dict, ops: list, data: dict, config: dict,
             seed: int) -> dict:
-    """Each number compared, with its limit.  All are counts of answers
-    that differ from the reference's, so every limit is 0."""
+    """Each number compared, with its limit.  All are counts, so every
+    limit is 0: of answers that differ from the reference's, and of the
+    programs JAX had to make inside the window (``window_programs``: a
+    window may only use what set-up made)."""
     import reference
 
     ref = reference.Client(data, config, seed)
@@ -483,5 +498,6 @@ def compare(program: dict, ops: list, data: dict, config: dict,
         "mined_patterns": differ(program["rounds"], ref.rounds),
         "cache_stats": sum(program["stats"].get(k) != v
                            for k, v in ref.stats.items()),
+        "window_programs": program["window_programs"],
     }
     return {k: {"value": v, "limit": 0} for k, v in checks.items()}

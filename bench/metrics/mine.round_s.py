@@ -1,6 +1,6 @@
 """mine.round_s: host seconds per mining round, from the client's own
-timer (``PalpatineClient.mining_wall_time`` over ``mining_runs``).  No
-cell mines in its window, so this is the set-up's first round."""
+timer (``PalpatineClient.mining_wall_time`` over ``mining_runs``): the
+mean over every round of the run, set-up's and the window's alike."""
 
 
 def install(run):

@@ -1,7 +1,7 @@
 """walk.readback_us: host microseconds per decision-walk call
 (``kernels/decision_walk/ops.py`` ``decision_walk``) in the program's
-``palp.walk.readback`` span: copying the step's six outputs back to the
-host."""
+``palp.walk.readback`` span: copying the step's one packed output back
+to the host."""
 
 import hostprofile
 

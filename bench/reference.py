@@ -22,7 +22,13 @@ configuration file states them:
   next levels, advances on each continuing read and dies on divergence;
 * a two-space LRU cache (main, and a preemptive share for prefetches);
 * one store node whose demand, background and write channels queue on
-  the virtual clock, with the seeded latency model's jitter.
+  the virtual clock, with the seeded latency model's jitter;
+* online mining (§4.2), where the configuration sets
+  ``online_mine_every``: every that many reads, counted since the last
+  online round, a round runs inside the read, after its prefetch and
+  before the clock advances; writes and explicit rounds leave the count
+  alone.  Every round, the explicit ones too, then mines only the last
+  ``online_tail_sessions`` sessions of each log.
 
 ``control`` names a guarantee the reference breaks on purpose, to show
 that the comparison catches it (see ``bench/control.py``).
@@ -384,6 +390,11 @@ class Client:
         self.targets: list = []        # main decisions, as key lists
         self.col_targets: list = []
         self.rounds: list = []         # per mining round: (main, col)
+        self.every = c.get("online_mine_every")
+        if self.every is not None and "online_tail_sessions" not in c:
+            raise ValueError("a configuration that mines online states "
+                             "online_tail_sessions")
+        self.reads_since_round = 0
 
     def read(self, key):
         c, now = self.c, self.now
@@ -405,6 +416,11 @@ class Client:
             self._prefetch(key, now)
             if col:
                 self._prefetch_columns(key, now)
+        if self.every is not None:
+            self.reads_since_round += 1
+            if self.reads_since_round >= self.every:
+                self.reads_since_round = 0
+                self.mine_now()
         self.now += latency
         return value, latency
 
@@ -461,6 +477,8 @@ class Client:
     def _sessions(self, log: Log) -> list:
         log.flush()
         s = log.sessions
+        if self.every is not None:
+            s = s[-self.c["online_tail_sessions"]:]
         if self.control == "sampled_mining":
             # support counted on every other session: half the work
             s = s[::2]

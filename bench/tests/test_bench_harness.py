@@ -32,7 +32,11 @@ SEED = 2**33 + 17          # seeds are larger than 32 bits
 
 def tiny(cell: str) -> dict:
     """The cell at a size a test run holds."""
-    spec = harness.load_cell(cell)
+    return shrink(harness.load_cell(cell))
+
+
+def shrink(spec: dict) -> dict:
+    """A cell's spec cut to a size a test run holds, by its generator."""
     data, mix = spec["config"]["data"], spec["mix"]
     if spec["mix"]["generator"] == "seqb":
         data.update(n_blocks=5_000, n_frequent=40)
@@ -129,15 +133,40 @@ def test_tpcc_new_order_writes_5_to_15_lines_that_read_back():
 def test_trace_reduction_on_a_small_trace():
     device = {"/device:TPU:0": [("fusion.1", 0, 10), ("frontier", 5, 10),
                                 ("fusion.1", 30, 10), ("late", 60, 10)]}
-    host = [("serve", 0, 20), ("serve", 22, 28), ("decide", 20, 8)]
+    host = [("serve", 0, 20), ("serve", 21, 29), ("decide", 22, 8)]
     r = devtrace.reduce_events(device, host)
     assert r["window_s"] == pytest.approx(50e-9)
     assert r["busy_s"] == pytest.approx(25e-9)      # [0,15) + [30,40)
     assert r["ops"] == pytest.approx({"fusion.1": 20e-9, "frontier": 10e-9})
     idle = dict(r["breakdown"]["idle_gaps"])
-    # [15,30) has its midpoint in decide, [40,50) in serve
+    # [15,30) has its midpoint in decide, inside serve; [40,50) in serve
     assert idle == pytest.approx({"decide": 15e-9, "serve": 10e-9})
     assert r["breakdown"]["device_ops"][0] == ["fusion.1", pytest.approx(20e-9)]
+
+    # the program's palp.* spans nest inside the harness's: each gap goes
+    # to the innermost span over its midpoint, and the busy time, the
+    # window and the operations stay as they were without them
+    device = {"/device:TPU:0": [("walk", 0, 10), ("walk", 40, 10),
+                                ("walk", 56, 2)]}
+    harness_spans = [("serve", 0, 100), ("decide", 6, 58)]
+    program_spans = [("palp.decide", 5, 60), ("palp.walk", 8, 50),
+                     ("palp.walk.upload", 8, 4), ("palp.walk.wait", 12, 20),
+                     ("palp.mine", 70, 25)]
+    other = [("jit_step", 0, 100)]
+    r = devtrace.reduce_events(device, harness_spans + program_spans + other)
+    bare = devtrace.reduce_events(device, harness_spans)
+    for key in ("busy_s", "window_s", "ops"):
+        assert r[key] == bare[key]
+    assert r["busy_s"] == pytest.approx(22e-9)
+    # [10,40) has its midpoint in the wait, [50,56) in the walk outside
+    # its phases, [58,100) in the mining round
+    assert dict(r["breakdown"]["idle_gaps"]) == pytest.approx(
+        {"palp.walk.wait": 30e-9, "palp.walk": 6e-9, "palp.mine": 42e-9})
+    assert dict(bare["breakdown"]["idle_gaps"]) == pytest.approx(
+        {"decide": 36e-9, "serve": 42e-9})
+    # spans that start together: the shorter is the inner
+    assert devtrace.innermost([("a", 0, 10), ("b", 0, 5)],
+                              [1, 7, 12]) == ["b", "a", None]
 
 
 def test_trace_reduction_reads_a_recorded_profile(tmp_path):
@@ -148,11 +177,13 @@ def test_trace_reduction_reads_a_recorded_profile(tmp_path):
     for _ in range(3):
         with jax.profiler.TraceAnnotation("serve"):
             with jax.profiler.TraceAnnotation("decide"):
-                jnp.ones((64, 64)).sum().block_until_ready()
+                with jax.profiler.TraceAnnotation("palp.walk"):
+                    jnp.ones((64, 64)).sum().block_until_ready()
     jax.profiler.stop_trace()
     device, host = devtrace.load(tmp_path)
     names = collections.Counter(n for n, _, _ in host)
     assert names["serve"] == 3 and names["decide"] == 3
+    assert names["palp.walk"] == 3
     r = devtrace.reduce_events(device, host)
     assert r["window_s"] > 0 and r["busy_s"] == 0.0     # no TPU plane here
 
@@ -208,6 +239,7 @@ def test_seqb_run_on_the_device_paths_is_correct(no_compile_cache):
     spec["config"]["client"]["dynamic_minsup_start"] = 0.05
     r = _run(spec, trace=True)
     assert r["correct"], r["checks"]
+    assert r["checks"]["window_programs"] == {"value": 0, "limit": 0}
     assert r["attempted"] > 0 and r["failed"] == 0
     assert set(r["metrics"]) <= {m["name"] for m in spec["per_layer"]}
     assert "decide.us_per_op" in r["metrics"]

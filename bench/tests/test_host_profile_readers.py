@@ -150,10 +150,11 @@ def test_a_traced_run_reports_every_new_metric(monkeypatch, tmp_path):
     m = {k: v["value"] for k, v in r["metrics"].items()}
     assert set(PROFILE_READERS + MINE_READERS) <= set(m)
     assert obs.host_profile is obs.NULL_HOST_PROFILE
-    assert m["walk.copies_per_call"] == 10.0
-    # three int32 context arrays and the bool alive mask, one row each
-    # per padded context
-    assert m["walk.upload_bytes"] % (3 * 4 + 1) == 0
+    assert m["walk.copies_per_call"] == 2.0
+    # one packed int32 upload: three context rows per padded context, the
+    # live count and the item
+    c = spec["config"]["semantics"]["max_contexts"]
+    assert m["walk.upload_bytes"] == (3 * c + 2) * 4
     assert 0 < sum(m[k] for k in WALK_US) <= m["decide.walk_call_us"]
     assert 0 <= m["decide.self_us_per_op"] <= m["decide.us_per_op"]
     assert 0 < m["device.idle_in_walk_pct"] <= m["device.idle_pct"]
