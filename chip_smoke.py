@@ -206,9 +206,11 @@ def run(n_blocks: int = N_BLOCKS, warm_sessions: int = WARM_SESSIONS,
         p, k, s, w = big
         # the Mosaic kernel lowers to a tpu_custom_call; the interpret
         # path would be plain XLA loops instead
-        text = bops.frontier_join_support.lower(
-            jax.ShapeDtypeStruct((p, s, w), np.uint32),
-            jax.ShapeDtypeStruct((k, s, w), np.uint32)).compile().as_text()
+        pb, kb, sb, wb = bops.frontier_calls(p, k, s, w)[-1]
+        text = bops.frontier_program.lower(
+            jax.ShapeDtypeStruct((wb, pb, sb), np.uint32),
+            jax.ShapeDtypeStruct((wb, kb, sb), np.uint32),
+            interpret=jax.default_backend() != "tpu").compile().as_text()
         checks["compiled Mosaic kernel"] = "tpu_custom_call" in text
         say(f"frontier path: {len(probe.frontier)} joins, "
             f"{sum(f[1] > 8 for f in probe.frontier)} with K > 8; largest "

@@ -157,6 +157,23 @@ class VectorizedPrefetchEngine:
             from repro.kernels.decision_walk import ops as _ops
             self._jax_forest = _ops.device_forest(self.flat)
 
+    def warm_walk(self, max_nodes: int) -> None:
+        """Make the device walk's program for every forest of up to
+        ``max_nodes`` nodes now (nothing on the numpy path)."""
+        if self.backend == "jax":
+            from repro.kernels.decision_walk import ops as _ops
+            _ops.warm_decision_walk(max_nodes, self.max_contexts,
+                                    self._p_depth)
+
+    def walk_program_made(self) -> bool:
+        """Whether this generation's walks can start no program: the
+        numpy path, an empty forest, or a device walk already made."""
+        if self.backend != "jax" or self.flat.n_nodes == 0:
+            return True
+        from repro.kernels.decision_walk import ops as _ops
+        return _ops.program_made(self._jax_forest, self.max_contexts,
+                                 self._p_depth)
+
     def _precompute_waves(self) -> None:
         flat, cfg = self.flat, self.cfg
         T = flat.n_trees

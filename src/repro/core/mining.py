@@ -60,6 +60,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from . import obs
 from .sessions import SequenceDatabase
 
 __all__ = [
@@ -97,6 +98,10 @@ class MiningParams:
     # byte cap on the frontier engine's transient join tensor; a walk whose
     # single-prefix K×S×W join exceeds it falls back to the DFS walker
     frontier_budget: int = 64 * 1024 * 1024
+    # the kernel join pads its session axis to at least this many
+    # sessions, so rounds over up to that many share its programs (an
+    # online client sets its tail)
+    join_min_sessions: int = 0
 
     def minsup_count(self, n_sessions: int) -> int:
         return max(1, int(math.ceil(self.minsup * n_sessions)))
@@ -127,6 +132,10 @@ class VerticalBitmaps:
     """
 
     def __init__(self, db: SequenceDatabase, minsup_count: int = 1):
+        with obs.host_profile.span(obs.SPAN_HOST_MINE_BITMAPS):
+            self._pack(db, minsup_count)
+
+    def _pack(self, db: SequenceDatabase, minsup_count: int) -> None:
         mat, _ = db.padded_matrix()
         self.n_sessions = mat.shape[0]
         max_len = mat.shape[1] if mat.size else 0
@@ -262,7 +271,9 @@ def _frontier_support(
     ~``support/S`` dense, so this skips the vast majority of the dense
     ``P×K×S×W`` work at low minsup).  Chunked so the transient stays under
     ``params.frontier_budget`` bytes.  ``use_kernel=True`` routes the dense
-    join through the Pallas ``frontier_join_support`` kernel instead.
+    join through the Pallas ``frontier_join_support`` kernel instead, on
+    the finite set of padded shapes that kernel's wrapper makes.  Each
+    call is the host-profile span ``palp.mine.join``.
 
     ``allowed`` is an optional (P,K) bool mask of candidate extensions per
     prefix (apriori narrowing for maxgap=None: a child's frequent
@@ -271,6 +282,16 @@ def _frontier_support(
     of the whole level — and disallowed pairs report support 0; the kernel
     path computes the dense join and masks after.
     """
+    with obs.host_profile.span(obs.SPAN_HOST_MINE_JOIN):
+        return _frontier_join(slots, cand, params, allowed)
+
+
+def _frontier_join(
+    slots: np.ndarray,
+    cand: np.ndarray,
+    params: MiningParams,
+    allowed: Optional[np.ndarray],
+) -> np.ndarray:
     p_prefixes, n_sessions, n_words = slots.shape
     k_items = cand.shape[0]
     if p_prefixes == 0 or k_items == 0:
@@ -278,7 +299,9 @@ def _frontier_support(
     if params.use_kernel:
         from repro.kernels.bitmap_support import ops as _ops
 
-        sup = np.asarray(_ops.frontier_join_support(slots, cand)).astype(np.int64)
+        sup = _ops.frontier_join_support(
+            slots, cand, min_sessions=params.join_min_sessions
+        ).astype(np.int64)
         if allowed is not None:
             sup[~allowed] = 0
         return sup

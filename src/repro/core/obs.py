@@ -35,7 +35,11 @@ Four instruments, one module:
   ``prefetch_hits`` counter (pinned by a tier-1 test).
 * **Host profile** — host-clock seconds, calls and child time per
   ``palp.*`` span, and plain counters, for the served path's layers
-  (decision engine, decision-walk kernel wrapper).  Off by default
+  (decision engine, decision-walk kernel wrapper) and the mining round
+  (``palp.mine`` around ``mine_now``, with its ``.bitmaps`` builds,
+  ``.join`` calls, ``.rebuild`` of trees and forest and ``.warm`` of
+  its programs; the ``palp.mine.join_h2d_bytes`` and
+  ``palp.mine.cold_programs`` counters).  Off by default
   through :data:`NULL_HOST_PROFILE`; :func:`set_host_profile` installs
   a :class:`HostProfile`, each of whose spans is also a
   ``jax.profiler.TraceAnnotation``, so it lands in a profiler trace on
@@ -98,6 +102,11 @@ SPAN_HOST_WALK_DISPATCH = "palp.walk.dispatch"  # jitted call returns
 SPAN_HOST_WALK_WAIT = "palp.walk.wait"          # block_until_ready
 SPAN_HOST_WALK_READBACK = "palp.walk.readback"  # device->host copies
 SPAN_HOST_WALK_UNPACK = "palp.walk.unpack"      # nonzero, slice, cast
+SPAN_HOST_MINE = "palp.mine"          # one mining round (``mine_now``)
+SPAN_HOST_MINE_BITMAPS = "palp.mine.bitmaps"    # a VerticalBitmaps build
+SPAN_HOST_MINE_JOIN = "palp.mine.join"          # a frontier join, answered
+SPAN_HOST_MINE_REBUILD = "palp.mine.rebuild"    # metastore, trees, forest
+SPAN_HOST_MINE_WARM = "palp.mine.warm"          # make the round's programs
 
 # zero-duration events attached to the innermost open span
 EVENT_HINT = "hint"
@@ -126,6 +135,10 @@ METRIC_WALK_H2D_COPIES = "palp.walk.h2d_copies"
 METRIC_WALK_H2D_BYTES = "palp.walk.h2d_bytes"
 METRIC_WALK_D2H_COPIES = "palp.walk.d2h_copies"
 METRIC_WALK_D2H_BYTES = "palp.walk.d2h_bytes"
+# host-profile counters of the mining round: bytes the frontier join
+# copies to the device, and programs a round made that set-up had not
+METRIC_MINE_JOIN_H2D_BYTES = "palp.mine.join_h2d_bytes"
+METRIC_MINE_COLD_PROGRAMS = "palp.mine.cold_programs"
 
 REGISTERED_NAMES = frozenset(
     v for k, v in list(globals().items())

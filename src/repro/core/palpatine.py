@@ -20,10 +20,14 @@ from . import obs
 from .metastore import PatternMetastore
 from .obs import (
     NULL_TRACER,
+    METRIC_MINE_COLD_PROGRAMS,
     SPAN_CACHE,
     SPAN_DECISION,
     SPAN_DEMAND,
     SPAN_HOST_DECIDE,
+    SPAN_HOST_MINE,
+    SPAN_HOST_MINE_REBUILD,
+    SPAN_HOST_MINE_WARM,
     SPAN_OP,
     SPAN_PREFETCH,
     EVENT_SHED,
@@ -77,7 +81,9 @@ class PalpatineConfig:
     # contexts multiply); False falls back to the scalar per-context
     # tree-walk oracle — the two are differentially identical
     use_vectorized: bool = True
-    # online mining (§4.2): re-mine every N logged operations (None = offline)
+    # online mining (§4.2): re-mine every N logged operations (None = offline);
+    # an online client makes every device program a round can use in its
+    # first round, so later rounds (inside a user's read) start none
     online_mine_every: Optional[int] = None
     online_tail_sessions: int = 2_000             # mine recent chunk only
     dynamic_minsup_start: float = 0.5
@@ -123,6 +129,10 @@ class PalpatineClient:
         #: feeds simulated time or mined results
         self.mining_wall_time = 0.0
         self.rebuild_wall_time = 0.0
+        #: device programs an online round needed that the first round's
+        #: warm-up had not made (``palp.mine.cold_programs``)
+        self.cold_programs = 0
+        self._warmed = False
         # packed-bitmap reuse across mining runs: {"main"/"col": (fp, vb)}
         self._vb_cache: dict = {}
         self._last_mine_events: Optional[int] = None
@@ -342,20 +352,74 @@ class PalpatineClient:
         return dynamic_floor_count(
             self.cfg.mining, len(db), self.cfg.dynamic_minsup_start, floor)
 
+    def _params(self) -> MiningParams:
+        """The mining parameters of a round: an online round's kernel join
+        pads its sessions to the tail, so every round shares its shapes."""
+        if self.cfg.online_mine_every is None:
+            return self.cfg.mining
+        return dataclasses.replace(
+            self.cfg.mining, join_min_sessions=self.cfg.online_tail_sessions)
+
+    def _tail(self, logger: AccessLogger):
+        db = logger.snapshot()
+        if self.cfg.online_mine_every is not None:
+            db = db.tail(self.cfg.online_tail_sessions)
+        return db
+
+    def _engines(self) -> list:
+        """The decision engines a round installs trees in."""
+        return ([self.engine, self.col_engine] if self.cfg.column_mining
+                else [self.engine])
+
+    def _join_programs(self) -> int:
+        if not self.cfg.mining.use_kernel:
+            return 0
+        from repro.kernels.bitmap_support import ops
+
+        return ops.programs_made()
+
+    def _warm(self, dbs) -> None:
+        """Make every device program a round of this configuration can
+        use: the frontier join's ladder for the tail's sessions and each
+        log's longest session, and the decision walk's node ladder up to
+        the metastore's bound (``metastore_capacity`` patterns of up to
+        ``max_len`` items)."""
+        self._warmed = True
+        with obs.host_profile.span(SPAN_HOST_MINE_WARM):
+            if self.cfg.mining.use_kernel:
+                from repro.kernels.bitmap_support import ops
+
+                longest = (max(map(len, db.sessions), default=1) for db in dbs)
+                for words in sorted({-(-n // 32) for n in longest}):
+                    ops.warm_frontier_join(self.cfg.online_tail_sessions,
+                                           words)
+            nodes = self.cfg.metastore_capacity * self.cfg.mining.max_len
+            for eng in self._engines():
+                warm = getattr(eng, "warm_walk", None)
+                if warm is not None:
+                    warm(nodes)
+
     def mine_now(self, use_dynamic_minsup: bool = True) -> int:
         """Run the Data Mining Engine on the backlog, furnish the metastore,
         rebuild the probabilistic trees.  Returns #patterns stored."""
+        with obs.host_profile.span(SPAN_HOST_MINE):
+            return self._mine_round(use_dynamic_minsup)
+
+    def _mine_round(self, use_dynamic_minsup: bool) -> int:
         t0 = obs.host_clock()
-        if self.cfg.column_mining:
-            self._mine_columns(use_dynamic_minsup)
-        db = self.logger.snapshot()
-        if self.cfg.online_mine_every is not None:
-            db = db.tail(self.cfg.online_tail_sessions)
+        col_db = self._tail(self.col_logger) if self.cfg.column_mining else None
+        db = self._tail(self.logger)
+        if self.cfg.online_mine_every is not None and not self._warmed:
+            self._warm([db] if col_db is None else [db, col_db])
+        joins = self._join_programs()
+        params = self._params()
+        if col_db is not None:
+            self._mine_columns(col_db, params, use_dynamic_minsup)
         if use_dynamic_minsup:
             floor_count = self._floor_count(db, self.cfg.dynamic_minsup_floor)
             vb = self._cached_bitmaps(self.logger, db, floor_count, "main")
             patterns, _ = mine_dynamic_minsup(
-                db, self.cfg.mining, self.cfg.algo,
+                db, params, self.cfg.algo,
                 start=self.cfg.dynamic_minsup_start,
                 floor=self.cfg.dynamic_minsup_floor,
                 min_patterns=self.cfg.min_patterns,
@@ -368,18 +432,25 @@ class PalpatineClient:
             vb = self._cached_bitmaps(self.logger, db, count, "main")
             if vb is None:
                 vb = self._build_bitmaps(self.logger, db, count, "main")
-            patterns = mine(db, self.cfg.mining, self.cfg.algo, vb=vb)
+            patterns = mine(db, params, self.cfg.algo, vb=vb)
         self.mining_runs += 1
         self._last_mine_events = self.logger.n_events
         # a sequence observed once is not a pattern: support >= 2 sessions
         patterns = [p for p in patterns if p.support >= 2]
         t1 = obs.host_clock()
-        self.metastore.populate(patterns)
-        self.engine.replace_index(PTreeIndex.build(self.metastore))
+        with obs.host_profile.span(SPAN_HOST_MINE_REBUILD):
+            self.metastore.populate(patterns)
+            self.engine.replace_index(PTreeIndex.build(self.metastore))
         t2 = obs.host_clock()
         self.rebuild_wall_time += t2 - t1
         self.mining_wall_time += t2 - t0
         self._last_mine_generation = self.metastore.generation
+        if self._warmed:
+            cold = self._join_programs() - joins + sum(
+                not getattr(eng, "walk_program_made", lambda: True)()
+                for eng in self._engines())
+            self.cold_programs += cold
+            obs.host_profile.count(METRIC_MINE_COLD_PROGRAMS, cold)
         return len(self.metastore)
 
     def backlog_unchanged_since_mine(self) -> bool:
@@ -410,16 +481,14 @@ class PalpatineClient:
             return (key[0], None, key[2])     # (table, *, column)
         return key
 
-    def _mine_columns(self, use_dynamic_minsup: bool = True) -> None:
-        db = self.col_logger.snapshot()
-        if self.cfg.online_mine_every is not None:
-            db = db.tail(self.cfg.online_tail_sessions)
+    def _mine_columns(self, db, params: MiningParams,
+                      use_dynamic_minsup: bool = True) -> None:
         floor = max(self.cfg.dynamic_minsup_floor, 2.0 / max(len(db), 1))
         if use_dynamic_minsup:
             floor_count = self._floor_count(db, floor)
             vb = self._cached_bitmaps(self.col_logger, db, floor_count, "col")
             patterns, _ = mine_dynamic_minsup(
-                db, self.cfg.mining, self.cfg.algo,
+                db, params, self.cfg.algo,
                 start=self.cfg.dynamic_minsup_start,
                 floor=floor,
                 min_patterns=self.cfg.min_patterns,
@@ -431,14 +500,15 @@ class PalpatineClient:
             vb = self._cached_bitmaps(self.col_logger, db, count, "col")
             if vb is None:
                 vb = self._build_bitmaps(self.col_logger, db, count, "col")
-            patterns = mine(db, self.cfg.mining, self.cfg.algo, vb=vb)
+            patterns = mine(db, params, self.cfg.algo, vb=vb)
         patterns = [p for p in patterns if p.support >= 2]
         t0 = obs.host_clock()
-        ms = PatternMetastore(self.cfg.metastore_capacity,
-                              self.cfg.mining.max_len)
-        ms.populate(patterns)
-        self.col_metastore = ms
-        self.col_engine.replace_index(PTreeIndex.build(ms))
+        with obs.host_profile.span(SPAN_HOST_MINE_REBUILD):
+            ms = PatternMetastore(self.cfg.metastore_capacity,
+                                  self.cfg.mining.max_len)
+            ms.populate(patterns)
+            self.col_metastore = ms
+            self.col_engine.replace_index(PTreeIndex.build(ms))
         self.rebuild_wall_time += obs.host_clock() - t0
 
     def _prefetch_columns(self, container, now: float) -> None:

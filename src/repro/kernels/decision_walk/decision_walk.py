@@ -13,7 +13,9 @@ item — a probability-matrix walk over the flattened pattern forest:
 * wave selection broadcasts each emitting context's depth band and DFS
   preorder interval against the whole node table, yielding a dense
   (C, N) wave mask whose row-major nonzeros are exactly the scalar
-  engine's (context order, level order) emission;
+  engine's (context order, level order) emission.  The preorder
+  interval keeps the wave inside the context's subtree, so the band is
+  a plain depth range;
 * :func:`top_k_frontier` is the jitted top-k frontier selection used for
   ``fetch_top_n`` initial waves (stable lexicographic (cum_prob desc,
   depth asc, level-order asc) pick, re-emitted (depth asc, cum desc)).
@@ -27,8 +29,11 @@ the wave mask bit-packed along N, little-endian — node ``32·w + b`` is
 bit ``b`` of word ``w`` (each uint32 word bitcast to int32), and the
 bits past N in the last word are 0.
 
-Shapes are static per mining generation (N nodes, E edges, C =
-``max_contexts``), so each generation compiles once.  The numpy
+Every node, edge and tree array is padded to one length N, a rung of
+a power-of-two ladder (:func:`.ops.node_bucket`), and C is the engine's
+``max_contexts``; the static arguments are ``p_depth`` and a search
+depth that follows N.  So a mining generation whose forest lands on a
+rung already made reuses its program.  The numpy
 reference in :mod:`.ref` delegates to the core engine's pure step
 functions; ``tests/test_decision_kernel.py`` pins jit-vs-reference
 parity.
@@ -47,12 +52,10 @@ __all__ = ["decision_walk_step", "top_k_frontier"]
 WORD_BITS = 32
 
 
-@partial(jax.jit,
-         static_argnames=("p_depth", "depth_stride", "search_steps"))
+@partial(jax.jit, static_argnames=("p_depth", "search_steps"))
 def decision_walk_step(edge_item, edge_child, edge_first, items, depth,
                        pre, post, n_children, tree_start, tree_max_depth,
-                       level_key, ctx, *, p_depth: int, depth_stride: int,
-                       search_steps: int):
+                       ctx, *, p_depth: int, search_steps: int):
     """Advance C (padded) contexts by ``item``; returns the packed
     (C, 5 + W) int32 output laid out in the module docstring.
 
@@ -62,7 +65,8 @@ def decision_walk_step(edge_item, edge_child, edge_first, items, depth,
     the largest ``n_children``.  Rows at and past ``n`` are dead: they
     never match, emit, or resurrect — zero-padding is decision-neutral,
     mirroring the support-neutral padding contract of
-    ``frontier_join_support``."""
+    ``frontier_join_support``.  Padded nodes have a preorder rank past
+    every real subtree's end, so no wave reaches them."""
     c = (ctx.shape[0] - 2) // 3
     nodes, trees, fetched = ctx[:c], ctx[c:2 * c], ctx[2 * c:3 * c]
     alive = jnp.arange(c) < ctx[3 * c]
@@ -90,9 +94,8 @@ def decision_walk_step(edge_item, edge_child, edge_first, items, depth,
                           | (n_children[new_nodes] == 0))
     new_alive = (found & ~dies_after) | stay
     new_fetched = jnp.where(emit, target, fetched)
-    lo = (trees * depth_stride + fetched + 1)[:, None]
-    hi = (trees * depth_stride + target)[:, None]
-    band = (level_key[None, :] >= lo) & (level_key[None, :] <= hi)
+    band = ((depth[None, :] > fetched[:, None])
+            & (depth[None, :] <= target[:, None]))
     sub = ((pre[None, :] >= pre[new_nodes][:, None])
            & (pre[None, :] < post[new_nodes][:, None]))
     wave_mask = band & sub & emit[:, None]

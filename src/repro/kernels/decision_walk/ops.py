@@ -1,76 +1,126 @@
 """Public wrappers for the jitted decision walk.
 
 ``device_forest`` ships one mining generation's :class:`FlatForest` to
-the device as int32 arrays (the device runs with x64 off) and refuses a
-forest whose ids would not fit; ``decision_walk`` packs the live context
-state, padded to the engine's ``max_contexts`` — keeping every shape
-static per generation, one compile each — with the live count and the
-item into one int32 vector, uploads it with one copy, runs the jitted
+the device as int32 arrays (the device runs with x64 off), each padded
+to the forest's rung of the node ladder (:func:`node_bucket`), and
+refuses a forest whose ids would not fit; ``decision_walk`` packs the
+live context state, padded to the engine's ``max_contexts``, with the
+live count and the item into one int32 vector, uploads it with one
+copy, runs the jitted
 step, reads its one packed output back with one copy, and unpacks that
 (the layout is in :mod:`.decision_walk`'s docstring) to the compact
 numpy state dict the core engine consumes.  Under an active host
 profile (:mod:`repro.core.obs`) a jitted call is the span ``palp.walk``,
 split into upload, dispatch, wait, readback and unpack, with its copies
 each way and their bytes counted.
+
+So the walk's programs are one per (rung, ``max_contexts``,
+``p_depth``), and a new generation on a rung already made starts none:
+:func:`warm_decision_walk` makes every rung up to a bound ahead.  A
+client's bound is its metastore's: at most ``metastore_capacity``
+patterns of at most ``max_len`` items, so at most ``capacity * max_len``
+nodes.
 """
 
 from __future__ import annotations
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
 from repro.core import obs
 
 from . import ref as _ref
 from .decision_walk import WORD_BITS, decision_walk_step, top_k_frontier
 
-__all__ = ["device_forest", "decision_walk", "top_k_frontier"]
+__all__ = ["device_forest", "decision_walk", "top_k_frontier",
+           "node_bucket", "node_ladder", "warm_decision_walk",
+           "programs_made", "program_made"]
 
 _INT32_MAX = np.iinfo(np.int32).max
 
+#: the smallest rung of the node ladder: one packed wave word
+MIN_NODES = 32
+
+#: the walk programs (rung, contexts, p_depth) this process has called
+#: or warmed
+_made: set = set()
+
+
+def node_bucket(n: int) -> int:
+    """The padded length of a forest's arrays: the next power of two
+    from ``MIN_NODES``."""
+    return max(MIN_NODES, 1 << (max(n, 1) - 1).bit_length())
+
+
+def node_ladder(max_nodes: int) -> list[int]:
+    """Every rung a forest of up to ``max_nodes`` nodes can land on."""
+    top = node_bucket(max_nodes)
+    return [1 << i for i in range(MIN_NODES.bit_length() - 1,
+                                  top.bit_length())]
+
+
+def programs_made() -> int:
+    """How many walk programs this process has made."""
+    return len(_made)
+
+
+def program_made(jf: "DeviceForest", max_contexts: int,
+                 p_depth: int) -> bool:
+    """Whether the walk over ``jf`` is a program this process has made."""
+    return (jf.n_padded, 3 * max_contexts + 2, p_depth) in _made
+
 
 class DeviceForest:
-    """Per-generation device-resident FlatForest arrays (int32).
+    """Per-generation device-resident FlatForest arrays (int32), each
+    padded to ``n_padded`` rows.
 
     The edge table is kept in its ``(parent, item)`` sort order as two
     parallel arrays, ``edge_item`` and ``edge_child``; ``edge_first[v]``
     is where node ``v``'s slice of it starts (its ``n_children`` edges
-    are contiguous because the table is sorted by parent first)."""
+    are contiguous because the table is sorted by parent first).
+    Padded rows are unreachable: no real node's edge slice covers a
+    padded edge, no context holds a padded node or tree, and a padded
+    node's preorder rank lies past every real subtree's end."""
 
     def __init__(self, flat):
         n = flat.n_nodes
-        # 2 * n bounds the search's lo + hi and every id, pre/post rank
-        # and edge index; level_key and the items bound the rest
-        biggest = max(2 * n, flat.item_stride,
-                      int(flat.level_key.max()) if n else 0)
+        self.n_padded = m = node_bucket(max(n, flat.n_trees + 1))
+        # 2 * m bounds the search's lo + hi and every id, pre/post rank
+        # and edge index; the items bound the rest
+        biggest = max(2 * m, flat.item_stride)
         if biggest > _INT32_MAX:
             raise OverflowError(
                 f"forest of {n} nodes over a vocabulary of "
                 f"{flat.item_stride} items does not fit the device walk's "
                 f"int32 ids")
-        # an empty edge table gets one unreachable entry (no node owns
-        # it: every n_children is 0) so the gathers stay shape-safe
-        edge_child = flat.edge_child if flat.edge_child.size else np.zeros(1)
-        edge_item = (flat.items[flat.edge_child] if flat.edge_child.size
-                     else np.full(1, -1))
-        self.edge_item = _i32(edge_item)
-        self.edge_child = _i32(edge_child)
-        self.edge_first = _i32(np.cumsum(flat.n_children) - flat.n_children)
-        self.search_steps = max(1, int(flat.n_children.max(initial=0))
-                                .bit_length())
-        self.items = _i32(flat.items)
-        self.depth = _i32(flat.depth)
-        self.pre = _i32(flat.pre)
-        self.post = _i32(flat.post)
-        self.n_children = _i32(flat.n_children)
-        self.tree_start = _i32(flat.tree_start)
-        self.tree_max_depth = _i32(flat.tree_max_depth)
-        self.level_key = _i32(flat.level_key)
+        self.search_steps = m.bit_length()
+        self.edge_item = _padded(flat.items[flat.edge_child], m, -1)
+        self.edge_child = _padded(flat.edge_child, m)
+        self.edge_first = _padded(np.cumsum(flat.n_children)
+                                  - flat.n_children, m)
+        self.items = _padded(flat.items, m, -1)
+        self.depth = _padded(flat.depth, m)
+        self.pre = _padded(flat.pre, m, m)
+        self.post = _padded(flat.post, m)
+        self.n_children = _padded(flat.n_children, m)
+        self.tree_start = _padded(flat.tree_start, m)
+        self.tree_max_depth = _padded(flat.tree_max_depth, m)
+        # the upload belongs to the generation's install, not its first
+        # walk
+        jax.block_until_ready(self.arrays())
+
+    def arrays(self) -> tuple:
+        """The step's forest arguments, in its order."""
+        return (self.edge_item, self.edge_child, self.edge_first,
+                self.items, self.depth, self.pre, self.post,
+                self.n_children, self.tree_start, self.tree_max_depth)
 
 
-def _i32(a) -> jnp.ndarray:
-    return jnp.asarray(np.asarray(a).astype(np.int32))
+def _padded(a, m: int, fill: int = 0) -> jax.Array:
+    """``a`` as int32, filled to ``m`` entries, on the device."""
+    out = np.full(m, fill, np.int32)
+    out[:len(a)] = a
+    return jax.device_put(out)
 
 
 def device_forest(flat) -> DeviceForest:
@@ -124,12 +174,10 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
                                 item if 0 <= item < flat.item_stride else -1)
             dev_ctx = jax.device_put(ctx)
         with prof.span(obs.SPAN_HOST_WALK_DISPATCH):
-            out = decision_walk_step(
-                jf.edge_item, jf.edge_child, jf.edge_first, jf.items,
-                jf.depth, jf.pre, jf.post, jf.n_children, jf.tree_start,
-                jf.tree_max_depth, jf.level_key, dev_ctx,
-                p_depth=p_depth, depth_stride=flat.depth_stride,
-                search_steps=jf.search_steps)
+            _made.add((jf.n_padded, len(ctx), p_depth))
+            out = decision_walk_step(*jf.arrays(), dev_ctx,
+                                     p_depth=p_depth,
+                                     search_steps=jf.search_steps)
         if prof.active:
             # unprofiled, the read-back below waits instead
             with prof.span(obs.SPAN_HOST_WALK_WAIT):
@@ -141,7 +189,7 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
             words = live[:, 5:]
             # expand only the words with bits set: (context, word, bit)
             # order is the wave's row-major order, and the device leaves
-            # the bits past N at 0
+            # the bits of padded nodes at 0
             _, w = np.nonzero(words)
             le_bytes = words[words != 0].astype("<i4").view(np.uint8)
             i, b = np.nonzero(np.unpackbits(le_bytes.reshape(-1, 4), axis=1,
@@ -162,3 +210,25 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
         prof.count(obs.METRIC_WALK_D2H_COPIES, 1)
         prof.count(obs.METRIC_WALK_D2H_BYTES, host_out.nbytes)
     return state
+
+
+def warm_decision_walk(max_nodes: int, max_contexts: int, p_depth: int,
+                       interpret: bool | None = None) -> int:
+    """Make the walk program of every rung up to ``max_nodes`` now, by
+    stepping no live context over an empty forest padded to each rung;
+    returns how many rungs there are.  ``interpret=True`` walks on the
+    numpy reference, as :func:`decision_walk` does, so it makes none."""
+    ladder = node_ladder(max_nodes)
+    if interpret:
+        return len(ladder)
+    ctx = jax.device_put(np.zeros(3 * max_contexts + 2, np.int32))
+    for m in ladder:
+        key = (m, len(ctx), p_depth)
+        if key in _made:
+            continue
+        z = _padded(np.zeros(0, np.int32), m)
+        jax.block_until_ready(decision_walk_step(
+            *([z] * 10), ctx, p_depth=p_depth,
+            search_steps=m.bit_length()))
+        _made.add(key)
+    return len(ladder)

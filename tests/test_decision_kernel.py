@@ -143,10 +143,11 @@ def test_decision_walk_same_with_host_profile_on(seed, monkeypatch):
             obs.METRIC_WALK_H2D_COPIES: 1,
             obs.METRIC_WALK_H2D_BYTES: (3 * 16 + 2) * 4,
             obs.METRIC_WALK_D2H_COPIES: 1,
-            obs.METRIC_WALK_D2H_BYTES: packed_bytes(16, flat.n_nodes),
+            obs.METRIC_WALK_D2H_BYTES: packed_bytes(16, jf.n_padded),
         }
-    # the step's one output is the bit-packed array, nothing more
-    assert out_bytes[-1] == packed_bytes(16, flat.n_nodes)
+    # the step's one output is the bit-packed array over the forest's
+    # rung of the node ladder, nothing more
+    assert out_bytes[-1] == packed_bytes(16, jf.n_padded)
 
 
 def branching_forest(n_nodes):

@@ -17,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bitmap_support import ops as bops
+from repro.kernels.decision_walk import ops as dw_ops
 from repro.kernels.decision_walk.decision_walk import decision_walk_step
 
 
@@ -54,10 +55,12 @@ def _shape(sharding, shape, dtype=jnp.uint32):
     (8, 1024, 5000, 4),      # 4-word sessions (up to 128 accesses)
 ])
 def test_frontier_join_compiles(one_chip, no_compile_cache, p, k, s, w):
-    compiled = bops.frontier_join_support.lower(
-        _shape(one_chip, (p, s, w)), _shape(one_chip, (k, s, w)),
-        interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    # every padded program the join of these shapes calls
+    for pb, kb, sb, wb in bops.frontier_calls(p, k, s, w):
+        compiled = bops.frontier_program.lower(
+            _shape(one_chip, (wb, pb, sb)), _shape(one_chip, (wb, kb, sb)),
+            interpret=False).compile()
+        assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("k,s,w", [(512, 5000, 1), (600, 5000, 4)])
@@ -69,22 +72,21 @@ def test_sstep_join_compiles(one_chip, no_compile_cache, k, s, w):
 
 
 def test_decision_walk_compiles(one_chip, no_compile_cache):
-    n, e, c, t = 100_000, 99_000, 256, 1_000    # nodes, edges, contexts, trees
+    # every array of a 100,000-node forest pads to its 131,072 rung
+    n, c = dw_ops.node_bucket(100_000), 256      # nodes, contexts
+    assert n == 131_072
     i32 = jnp.int32
     node = _shape(one_chip, (n,), i32)
     compiled = decision_walk_step.lower(
-        _shape(one_chip, (e,), i32), _shape(one_chip, (e,), i32),
-        node, node, node, node, node, node,
-        _shape(one_chip, (t + 1,), i32), _shape(one_chip, (t,), i32), node,
-        _shape(one_chip, (3 * c + 2,), i32),
-        p_depth=2, depth_stride=12, search_steps=17).compile()
+        *([node] * 10), _shape(one_chip, (3 * c + 2,), i32),
+        p_depth=2, search_steps=n.bit_length()).compile()
     # one packed output: five state columns and the wave mask's
-    # ceil(N / 32) words per context, all int32
-    cols = 5 + -(-n // 32)
+    # N / 32 words per context, all int32
+    cols = 5 + n // 32
     out = compiled.out_info
     assert (out.shape, out.dtype) == ((c, cols), jnp.int32)
     # on the chip C (256, whole lanes) is the minor dimension and the
-    # 3,130 columns fill whole 8-row tiles: 3,136 of them
+    # 4,101 columns fill whole 8-row tiles: 4,104 of them
     mem = compiled.memory_analysis()
     assert mem.output_size_in_bytes == c * (-(-cols // 8) * 8) * 4
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < 16e9
