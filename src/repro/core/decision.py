@@ -28,7 +28,9 @@ differentially across the heuristic × workload grid.
 ``backend="jax"`` routes the advance + wave selection through the jitted
 twin in :mod:`repro.kernels.decision_walk` (same contract as the
 mining engine's ``use_kernel`` Pallas path); the numpy path is the
-dependency-free default and the one the tier-1 suite exercises.
+dependency-free default and the one the tier-1 suite exercises.  On
+that path ``begin_walk`` starts the next request's walk early, so that
+a client with two engines runs one's round trip under the other's.
 """
 
 from __future__ import annotations
@@ -134,6 +136,8 @@ class VectorizedPrefetchEngine:
         # bare walk.
         self.attribute = False
         self._last_nodes: np.ndarray | None = None
+        #: (item, generation, op, walk) of a walk begun ahead, or None
+        self._pending: tuple | None = None
         self.replace_index(index)
 
     # ------------------------------------------------------------------
@@ -164,6 +168,32 @@ class VectorizedPrefetchEngine:
             from repro.kernels.decision_walk import ops as _ops
             _ops.warm_decision_walk(max_nodes, self.max_contexts,
                                     self._p_depth)
+
+    def begin_walk(self, item: int) -> None:
+        """Start the device walk that ``on_request(item)`` would make of
+        the live contexts, and return without waiting for it: the host
+        is free for other work, such as another engine's walk, while the
+        step runs and its output comes back.  The next ``on_request``
+        consumes it only if that request is for ``item``, on the same
+        generation, with no request in between; otherwise it walks as
+        if none had begun.  A no-op on the numpy path and with no live
+        context."""
+        if self.backend != "jax" or not self._n:
+            return
+        from repro.kernels.decision_walk import ops as _ops
+        n, item = self._n, int(item)
+        self._pending = (item, self.index, self._op, _ops.start_walk(
+            self._jax_forest, self.flat, self._node[:n], self._tree[:n],
+            self._fetched[:n], item, self._p_depth,
+            max_contexts=self.max_contexts))
+
+    def _take_pending(self, item: int):
+        """The walk begun ahead, if it was begun for this request."""
+        p, self._pending = self._pending, None
+        if (p is not None and p[0] == item and p[1] is self.index
+                and p[2] == self._op):
+            return p[3]
+        return None
 
     def walk_program_made(self) -> bool:
         """Whether this generation's walks can start no program: the
@@ -281,20 +311,24 @@ class VectorizedPrefetchEngine:
         self._init_fetched = flat.tree_max_depth
 
     # ------------------------------------------------------------------
-    def _advance(self, item: int) -> tuple[list[np.ndarray],
-                                           list[np.ndarray]]:
+    def _advance(self, item: int, begun=None) -> tuple[list[np.ndarray],
+                                                       list[np.ndarray]]:
         """Advance all live contexts; returns the advancement wave item
         arrays (context-major) plus — when ``attribute`` is on — the
         parallel wave node-id arrays, and compacts the survivors in
-        place."""
+        place.  ``begun`` is this request's walk begun ahead, if any."""
         n = self._n
         flat = self.flat
         nodes, trees = self._node[:n], self._tree[:n]
         if self.backend == "jax":
             from repro.kernels.decision_walk import ops as _ops
-            st = _ops.decision_walk(
-                self._jax_forest, flat, nodes, trees, self._fetched[:n],
-                item, self._p_depth, max_contexts=self.max_contexts)
+            if begun is not None:
+                st = _ops.finish_walk(begun)
+            else:
+                st = _ops.decision_walk(
+                    self._jax_forest, flat, nodes, trees,
+                    self._fetched[:n], item, self._p_depth,
+                    max_contexts=self.max_contexts)
             parts: list[np.ndarray] = []
             nparts: list[np.ndarray] = []
             if len(st["wave_nodes"]):
@@ -359,9 +393,10 @@ class VectorizedPrefetchEngine:
     def on_request(self, item: int) -> list[int]:
         """Returns item ids to prefetch (deduplicated, wave order kept) —
         one array program regardless of how many contexts are live."""
-        self._op += 1
         item = int(item)
-        parts, nparts = self._advance(item) if self._n else ([], [])
+        begun = self._take_pending(item)
+        self._op += 1
+        parts, nparts = self._advance(item, begun) if self._n else ([], [])
         flat = self.flat
         t = flat.root_tree.get(item)
         if t is not None:

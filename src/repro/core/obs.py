@@ -135,6 +135,8 @@ METRIC_WALK_H2D_COPIES = "palp.walk.h2d_copies"
 METRIC_WALK_H2D_BYTES = "palp.walk.h2d_bytes"
 METRIC_WALK_D2H_COPIES = "palp.walk.d2h_copies"
 METRIC_WALK_D2H_BYTES = "palp.walk.d2h_bytes"
+# decision walks begun ahead of the call that consumes them
+METRIC_WALK_OVERLAPPED = "palp.walk.overlapped"
 # host-profile counters of the mining round: bytes the frontier join
 # copies to the device, and programs a round made that set-up had not
 METRIC_MINE_JOIN_H2D_BYTES = "palp.mine.join_h2d_bytes"
@@ -385,7 +387,7 @@ class NullHostProfile:
 
     active = False
 
-    def span(self, name: str) -> contextlib.nullcontext:
+    def span(self, name: str, call: bool = True) -> contextlib.nullcontext:
         return _NULL_CONTEXT
 
     def count(self, name: str, n: int = 1) -> None:
@@ -397,13 +399,15 @@ NULL_HOST_PROFILE = NullHostProfile()
 
 class _HostSpan:
     """One open host-profile span; closing it books its host seconds,
-    its children's total and one call under its name."""
+    its children's total and, unless it goes on a call already booked,
+    one call under its name."""
 
-    __slots__ = ("profile", "name", "annotation", "t0", "child")
+    __slots__ = ("profile", "name", "call", "annotation", "t0", "child")
 
-    def __init__(self, profile: "HostProfile", name: str):
+    def __init__(self, profile: "HostProfile", name: str, call: bool):
         self.profile = profile
         self.name = name
+        self.call = call
 
     def __enter__(self) -> "_HostSpan":
         p = self.profile
@@ -421,7 +425,7 @@ class _HostSpan:
         self.annotation.__exit__(*exc)
         name = self.name
         p.seconds[name] = p.seconds.get(name, 0.0) + dt
-        p.calls[name] = p.calls.get(name, 0) + 1
+        p.calls[name] = p.calls.get(name, 0) + self.call
         p.child_seconds[name] = p.child_seconds.get(name, 0.0) + self.child
         if p._stack:
             p._stack[-1].child += dt
@@ -449,8 +453,10 @@ class HostProfile:
         self.child_seconds: dict[str, float] = {}
         self.counters: dict[str, int] = {}
 
-    def span(self, name: str) -> _HostSpan:
-        return _HostSpan(self, name)
+    def span(self, name: str, call: bool = True) -> _HostSpan:
+        """A span of ``name``; ``call=False`` books its seconds on a call
+        of ``name`` already booked (the second part of a split call)."""
+        return _HostSpan(self, name, call)
 
     def count(self, name: str, n: int = 1) -> None:
         self.counters[name] = self.counters.get(name, 0) + n

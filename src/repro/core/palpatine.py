@@ -202,9 +202,10 @@ class PalpatineClient:
                     self.cache.put_demand(iid, value, len(value))
 
             if self.cfg.prefetch_enabled:
+                gen_iid = self._begin_column_walk(container)
                 self._prefetch(iid, now)
-                if self.cfg.column_mining:
-                    self._prefetch_columns(container, now)
+                if gen_iid is not None:
+                    self._prefetch_columns(container, gen_iid, now)
             self._maybe_online_mine()
             self.clock.advance(latency)
             sp.finish(now + latency)
@@ -280,9 +281,10 @@ class PalpatineClient:
             latency = (done_at - now) + CACHE_OVERHEAD * len(containers)
             if self.cfg.prefetch_enabled:
                 for iid, container in zip(iids, containers):
+                    gen_iid = self._begin_column_walk(container)
                     self._prefetch(iid, now)
-                    if self.cfg.column_mining:
-                        self._prefetch_columns(container, now)
+                    if gen_iid is not None:
+                        self._prefetch_columns(container, gen_iid, now)
             self._maybe_online_mine()
             self.clock.advance(latency)
             sp.finish(now + latency)
@@ -511,14 +513,26 @@ class PalpatineClient:
             self.col_engine.replace_index(PTreeIndex.build(ms))
         self.rebuild_wall_time += obs.host_clock() - t0
 
-    def _prefetch_columns(self, container, now: float) -> None:
+    def _begin_column_walk(self, container) -> Optional[int]:
+        """The column engine's item for a read of a ``(table, row,
+        column)`` cell, with the engine's device walk for it begun, so
+        that it runs while ``_prefetch`` walks the main engine; None
+        where column mining is off or the key is not a cell.  Nothing
+        ``_prefetch`` does changes what the column engine decides."""
+        if not self.cfg.column_mining:
+            return None
+        key = self._store_key(container)
+        if not (isinstance(key, tuple) and len(key) == 3):
+            return None
+        gen_iid = self.col_logger.db.item_id(self._generalize(container))
+        self.col_engine.begin_walk(gen_iid)
+        return gen_iid
+
+    def _prefetch_columns(self, container, gen_iid: int,
+                          now: float) -> None:
         """Instantiate predicted (table, column) containers with the
         triggering request's row and prefetch the concrete cells."""
-        key = container.key() if hasattr(container, "key") else container
-        if not (isinstance(key, tuple) and len(key) == 3):
-            return
-        row = key[1]
-        gen_iid = self.col_logger.db.item_id(self._generalize(container))
+        row = self._store_key(container)[1]
         with obs.host_profile.span(SPAN_HOST_DECIDE):
             targets = self.col_engine.on_request(gen_iid)
         if not targets:

@@ -29,6 +29,14 @@ the wave mask bit-packed along N, little-endian — node ``32·w + b`` is
 bit ``b`` of word ``w`` (each uint32 word bitcast to int32), and the
 bits past N in the last word are 0.
 
+The host may run a call in two halves (:func:`.ops.start_walk`, then
+:func:`.ops.finish_walk`): dispatch the step and ask for the output's
+copy back at once, then collect the output later, after other host
+work.  An engine consumes such a begun walk only in its next request,
+for the item it was begun for, on the generation it was begun on
+(:meth:`repro.core.decision.VectorizedPrefetchEngine.begin_walk`).  The
+step is the same program either way.
+
 Every node, edge and tree array is padded to one length N, a rung of
 a power-of-two ladder (:func:`.ops.node_bucket`), and C is the engine's
 ``max_contexts``; the static arguments are ``p_depth`` and a search

@@ -20,6 +20,19 @@ So the walk's programs are one per (rung, ``max_contexts``,
 client's bound is its metastore's: at most ``metastore_capacity``
 patterns of at most ``max_len`` items, so at most ``capacity * max_len``
 nodes.
+
+A walk can also run in two halves, so that the host does other work,
+another walk's round trip included, while the device steps and the
+output comes back.  :func:`start_walk` uploads, dispatches and asks for
+the output's copy to the host, then returns a :class:`PendingWalk`;
+:func:`finish_walk` waits for it, reads it back and unpacks it, and
+gives what ``decision_walk`` gives for the same arguments.  Both halves
+call the same step on the same rungs, so they start no program of
+their own.  A begun walk books one ``palp.walk`` call, upload and
+dispatch at the start, wait, read-back and unpack at the finish, the
+same two copies, and one ``palp.walk.overlapped``.
+:meth:`repro.core.decision.VectorizedPrefetchEngine.begin_walk` says
+when an engine consumes a begun walk.
 """
 
 from __future__ import annotations
@@ -32,7 +45,8 @@ from repro.core import obs
 from . import ref as _ref
 from .decision_walk import WORD_BITS, decision_walk_step, top_k_frontier
 
-__all__ = ["device_forest", "decision_walk", "top_k_frontier",
+__all__ = ["device_forest", "decision_walk", "start_walk", "finish_walk",
+           "PendingWalk", "top_k_frontier",
            "node_bucket", "node_ladder", "warm_decision_walk",
            "programs_made", "program_made"]
 
@@ -138,6 +152,98 @@ def _pad_and_pack(c: int, nodes, trees, fetched, item: int) -> np.ndarray:
     return ctx
 
 
+class PendingWalk:
+    """A walk :func:`start_walk` began: its packed output on its way back
+    to the host, or the state of a walk that took no device."""
+
+    __slots__ = ("out", "n", "h2d_bytes", "state")
+
+    def __init__(self, out=None, n: int = 0, h2d_bytes: int = 0,
+                 state: dict | None = None):
+        self.out = out
+        self.n = n
+        self.h2d_bytes = h2d_bytes
+        self.state = state
+
+
+def _on_host(flat, nodes, trees, fetched, item: int, p_depth: int,
+             interpret: bool | None) -> dict | None:
+    """The state of a walk that takes no device (the reference when
+    ``interpret``, or a forest with no node), else None."""
+    if interpret:
+        return _ref.decision_walk_ref(flat, nodes, trees, fetched,
+                                      item, p_depth)
+    if flat.n_nodes:
+        return None
+    # zero-node forest: nothing to gather against — every context is
+    # dead by construction (none could have been opened)
+    n = len(nodes)
+    z = np.zeros(n, np.int64)
+    f = np.zeros(n, bool)
+    return {"found": f, "stay": f.copy(), "nodes": z, "alive": f.copy(),
+            "fetched": z.copy(), "wave_nodes": np.empty(0, np.int64)}
+
+
+def _start(jf: DeviceForest, flat, nodes, trees, fetched, item: int,
+           p_depth: int, max_contexts: int | None, ahead: bool,
+           prof) -> PendingWalk:
+    """Upload the packed context with one copy and dispatch the step;
+    ``ahead`` also asks for the output's copy to the host, which then
+    starts when the step ends."""
+    n = len(nodes)
+    with prof.span(obs.SPAN_HOST_WALK_UPLOAD):
+        ctx = _pad_and_pack(max_contexts or max(n, 1), nodes, trees,
+                            fetched,
+                            item if 0 <= item < flat.item_stride else -1)
+        dev_ctx = jax.device_put(ctx)
+    with prof.span(obs.SPAN_HOST_WALK_DISPATCH):
+        _made.add((jf.n_padded, len(ctx), p_depth))
+        out = decision_walk_step(*jf.arrays(), dev_ctx, p_depth=p_depth,
+                                 search_steps=jf.search_steps)
+        if ahead:
+            out.copy_to_host_async()
+    return PendingWalk(out, n, ctx.nbytes)
+
+
+def _finish(walk: PendingWalk, prof) -> dict:
+    """Read the packed output back with one copy and unpack it."""
+    out = walk.out
+    if prof.active:
+        # unprofiled, the read-back below waits instead
+        with prof.span(obs.SPAN_HOST_WALK_WAIT):
+            jax.block_until_ready(out)
+    with prof.span(obs.SPAN_HOST_WALK_READBACK):
+        host_out = np.asarray(out)
+    with prof.span(obs.SPAN_HOST_WALK_UNPACK):
+        live = host_out[:walk.n]
+        words = live[:, 5:]
+        # expand only the words with bits set: (context, word, bit)
+        # order is the wave's row-major order, and the device leaves
+        # the bits of padded nodes at 0
+        _, w = np.nonzero(words)
+        le_bytes = words[words != 0].astype("<i4").view(np.uint8)
+        i, b = np.nonzero(np.unpackbits(le_bytes.reshape(-1, 4), axis=1,
+                                        bitorder="little"))
+        wave_nodes = w[i] * WORD_BITS + b
+        i64 = np.int64
+        return {
+            "found": live[:, 3].astype(bool),
+            "stay": live[:, 4].astype(bool),
+            "nodes": live[:, 0].astype(i64),
+            "alive": live[:, 2].astype(bool),
+            "fetched": live[:, 1].astype(i64),
+            "wave_nodes": wave_nodes.astype(i64),
+        }
+
+
+def _count_copies(walk: PendingWalk, prof) -> None:
+    if prof.active:
+        prof.count(obs.METRIC_WALK_H2D_COPIES, 1)
+        prof.count(obs.METRIC_WALK_H2D_BYTES, walk.h2d_bytes)
+        prof.count(obs.METRIC_WALK_D2H_COPIES, 1)
+        prof.count(obs.METRIC_WALK_D2H_BYTES, walk.out.nbytes)
+
+
 def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
                   item: int, p_depth: int,
                   max_contexts: int | None = None,
@@ -154,61 +260,47 @@ def decision_walk(jf: DeviceForest, flat, nodes, trees, fetched,
     — for debugging and for environments where tracing itself is the
     suspect.  The default (``None``/``False``) keeps the jitted path,
     which runs on any backend (CPU-jit included)."""
-    if interpret:
-        return _ref.decision_walk_ref(flat, nodes, trees, fetched,
-                                      item, p_depth)
-    n = len(nodes)
-    if flat.n_nodes == 0:
-        # zero-node forest: nothing to gather against — every context is
-        # dead by construction (none could have been opened)
-        z = np.zeros(n, np.int64)
-        f = np.zeros(n, bool)
-        return {"found": f, "stay": f.copy(), "nodes": z,
-                "alive": f.copy(), "fetched": z.copy(),
-                "wave_nodes": np.empty(0, np.int64)}
+    state = _on_host(flat, nodes, trees, fetched, item, p_depth, interpret)
+    if state is not None:
+        return state
     prof = obs.host_profile
     with prof.span(obs.SPAN_HOST_WALK):
-        with prof.span(obs.SPAN_HOST_WALK_UPLOAD):
-            ctx = _pad_and_pack(max_contexts or max(n, 1), nodes, trees,
-                                fetched,
-                                item if 0 <= item < flat.item_stride else -1)
-            dev_ctx = jax.device_put(ctx)
-        with prof.span(obs.SPAN_HOST_WALK_DISPATCH):
-            _made.add((jf.n_padded, len(ctx), p_depth))
-            out = decision_walk_step(*jf.arrays(), dev_ctx,
-                                     p_depth=p_depth,
-                                     search_steps=jf.search_steps)
-        if prof.active:
-            # unprofiled, the read-back below waits instead
-            with prof.span(obs.SPAN_HOST_WALK_WAIT):
-                jax.block_until_ready(out)
-        with prof.span(obs.SPAN_HOST_WALK_READBACK):
-            host_out = np.asarray(out)
-        with prof.span(obs.SPAN_HOST_WALK_UNPACK):
-            live = host_out[:n]
-            words = live[:, 5:]
-            # expand only the words with bits set: (context, word, bit)
-            # order is the wave's row-major order, and the device leaves
-            # the bits of padded nodes at 0
-            _, w = np.nonzero(words)
-            le_bytes = words[words != 0].astype("<i4").view(np.uint8)
-            i, b = np.nonzero(np.unpackbits(le_bytes.reshape(-1, 4), axis=1,
-                                            bitorder="little"))
-            wave_nodes = w[i] * WORD_BITS + b
-            i64 = np.int64
-            state = {
-                "found": live[:, 3].astype(bool),
-                "stay": live[:, 4].astype(bool),
-                "nodes": live[:, 0].astype(i64),
-                "alive": live[:, 2].astype(bool),
-                "fetched": live[:, 1].astype(i64),
-                "wave_nodes": wave_nodes.astype(i64),
-            }
-    if prof.active:
-        prof.count(obs.METRIC_WALK_H2D_COPIES, 1)
-        prof.count(obs.METRIC_WALK_H2D_BYTES, ctx.nbytes)
-        prof.count(obs.METRIC_WALK_D2H_COPIES, 1)
-        prof.count(obs.METRIC_WALK_D2H_BYTES, host_out.nbytes)
+        walk = _start(jf, flat, nodes, trees, fetched, item, p_depth,
+                      max_contexts, False, prof)
+        state = _finish(walk, prof)
+    _count_copies(walk, prof)
+    return state
+
+
+def start_walk(jf: DeviceForest, flat, nodes, trees, fetched, item: int,
+               p_depth: int, max_contexts: int | None = None,
+               interpret: bool | None = None) -> PendingWalk:
+    """The first half of :func:`decision_walk`: upload, dispatch and ask
+    for the output's copy to the host, then return without waiting for
+    either.  :func:`finish_walk` gives the state ``decision_walk`` would
+    have given for the same arguments.  The context arrays are copied
+    here, so the caller may change them before the finish."""
+    state = _on_host(flat, nodes, trees, fetched, item, p_depth, interpret)
+    if state is not None:
+        return PendingWalk(state=state)
+    prof = obs.host_profile
+    with prof.span(obs.SPAN_HOST_WALK):
+        walk = _start(jf, flat, nodes, trees, fetched, item, p_depth,
+                      max_contexts, True, prof)
+    prof.count(obs.METRIC_WALK_OVERLAPPED, 1)
+    return walk
+
+
+def finish_walk(walk: PendingWalk) -> dict:
+    """The second half: wait for a begun walk's output, read it back and
+    unpack it.  Its seconds go on the ``palp.walk`` call that
+    :func:`start_walk` booked."""
+    if walk.state is not None:
+        return walk.state
+    prof = obs.host_profile
+    with prof.span(obs.SPAN_HOST_WALK, call=False):
+        state = _finish(walk, prof)
+    _count_copies(walk, prof)
     return state
 
 
