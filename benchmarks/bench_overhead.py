@@ -37,7 +37,8 @@ import numpy as np
 
 from repro.core import (
     BaselineClient, HeuristicConfig, MiningParams, PalpatineClient,
-    PalpatineConfig, Pattern, PTreeIndex, SimulatedDKVStore, build_engine,
+    PalpatineConfig, Pattern, PrefetchEngine, PTreeIndex, SimulatedDKVStore,
+    VectorizedPrefetchEngine,
 )
 from repro.core.obs import NULL_TRACER, Tracer
 
@@ -108,9 +109,9 @@ def bench_decision(results: dict, quick: bool) -> None:
         n_ops = len(stream) - steady
         cfg = HeuristicConfig("fetch_progressive", progressive_depth=3)
         us = {}
-        for label, vec in (("scalar", False), ("vectorized", True)):
-            eng = build_engine(index, cfg, max_contexts=256,
-                               use_vectorized=vec)
+        for label, engine in (("scalar", PrefetchEngine),
+                              ("vectorized", VectorizedPrefetchEngine)):
+            eng = engine(index, cfg, max_contexts=256)
             wall = _median_wall(
                 lambda e=eng: _decision_pass(e, index, stream, steady),
                 reps)
@@ -217,7 +218,8 @@ def bench_tracing(results: dict, quick: bool) -> None:
     # (chain items and decoys) before the engine can emit them
     for i in range(n_ids):
         pal.logger.db.item_id(i)
-    pal.engine = build_engine(index, pal.cfg.heuristic, max_contexts=64)
+    pal.engine = VectorizedPrefetchEngine(index, pal.cfg.heuristic,
+                                          max_contexts=64)
     pal.engine.attribute = True
 
     def one_pass():

@@ -74,12 +74,6 @@ def main():
             heuristic=HeuristicConfig("fetch_progressive"),
             cache_bytes=64 * 1024,
             mining=MiningParams(minsup=0.05, min_len=3, max_len=10, maxgap=1),
-            # every tenant decides prefetches on the vectorized array
-            # engine (the default): one batched walk per request however
-            # many contexts are live.  Set False to run the scalar
-            # per-context oracle — outputs are identical, only the
-            # per-op cost changes.
-            use_vectorized=True,
         )))
     warm0, warm1, cold = cluster.tenants
 

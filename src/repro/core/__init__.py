@@ -19,7 +19,7 @@ from .cluster import (
     ShardedTwoSpaceCache,
     VerdictExchange,
 )
-from .decision import VectorizedPrefetchEngine, build_engine
+from .decision import VectorizedPrefetchEngine
 from .heuristics import HEURISTICS, HeuristicConfig, PrefetchEngine
 from .membership import (
     BudgetRebalancer,
@@ -83,6 +83,6 @@ __all__ = [
     "ShardedTwoSpaceCache", "SimulatedDKVStore", "TwoSpaceCache",
     "VectorizedPrefetchEngine", "VerdictBoard", "VerdictExchange",
     "VerticalBitmaps", "brute_force",
-    "build_engine", "concurrent", "descends", "merge",
+    "concurrent", "descends", "merge",
     "mine", "mine_dynamic_minsup",
 ]

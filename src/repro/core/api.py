@@ -56,7 +56,7 @@ class Client(Protocol):
         """Explicit session cut (end of a request/transaction)."""
         ...
 
-    def mine_now(self, use_dynamic_minsup: bool = True) -> int:
+    def mine_now(self) -> int:
         """Mine the logged backlog into the pattern metastore."""
         ...
 

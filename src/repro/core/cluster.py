@@ -1594,11 +1594,10 @@ class PatternExchange:
         """Merge the cluster's patterns into ``client`` and rebuild its
         probabilistic trees — a cold client warms up from its peers.
 
-        ``replace_index`` is engine-agnostic: on a vectorized client
-        (``PalpatineConfig.use_vectorized``) it also flattens the new
-        forest into the CSR arrays the batched decision walk consumes,
-        so the pull carries the one-time flatten cost of a mining
-        generation, not the per-op path."""
+        ``replace_index`` also flattens the new forest into the CSR
+        arrays the batched decision walk consumes, so the pull carries
+        the one-time flatten cost of a mining generation, not the per-op
+        path."""
         n = 0
         if len(self.store):
             local = [Pattern(client.logger.db.encode(p.items), p.support)

@@ -37,12 +37,11 @@ from __future__ import annotations
 
 import numpy as np
 
-from .heuristics import HeuristicConfig, PrefetchEngine
+from .heuristics import HeuristicConfig
 from .obs import PrefetchCause
 from .ptree import FlatForest, PTreeIndex
 
-__all__ = ["VectorizedPrefetchEngine", "build_engine", "advance_step",
-           "wave_select"]
+__all__ = ["VectorizedPrefetchEngine", "advance_step", "wave_select"]
 
 
 def _ranges_concat(a: np.ndarray, b: np.ndarray) -> tuple[np.ndarray,
@@ -462,13 +461,3 @@ class VectorizedPrefetchEngine:
                                    flat.depth[nodes].tolist(),
                                    flat.cum_prob[nodes].tolist())]
 
-
-def build_engine(index: PTreeIndex, cfg: HeuristicConfig,
-                 max_contexts: int = 256, use_vectorized: bool = True,
-                 backend: str = "numpy"):
-    """Engine factory the clients share: the vectorized array walk by
-    default, the scalar oracle when ``use_vectorized=False``."""
-    if use_vectorized:
-        return VectorizedPrefetchEngine(index, cfg, max_contexts,
-                                        backend=backend)
-    return PrefetchEngine(index, cfg, max_contexts)

@@ -164,9 +164,6 @@ class PrefetchEngine:
         self.index = index
         self.contexts = []
 
-    def begin_walk(self, item: int) -> None:
-        """No device walk to begin ahead: the scalar walk is host work."""
-
     def on_request(self, item: int) -> list[int]:
         """Returns item ids to prefetch (deduplicated, wave order kept)."""
         self._op += 1

@@ -76,6 +76,8 @@ __all__ = [
     "maximal_filter",
     "mine_dynamic_minsup",
     "dynamic_floor_count",
+    "warm_join",
+    "join_programs",
     "brute_force",
 ]
 
@@ -284,6 +286,31 @@ def _frontier_support(
     """
     with obs.host_profile.span(obs.SPAN_HOST_MINE_JOIN):
         return _frontier_join(slots, cand, params, allowed)
+
+
+def warm_join(params: MiningParams,
+              dbs: Sequence[SequenceDatabase]) -> None:
+    """Make now every frontier-join program that a round over ``dbs``
+    with these parameters can call: for the word count of each log's
+    longest session, with sessions padded to ``params.join_min_sessions``.
+    Nothing unless ``params.use_kernel``."""
+    if not params.use_kernel:
+        return
+    from repro.kernels.bitmap_support import ops as _ops
+
+    longest = (max(map(len, db.sessions), default=1) for db in dbs)
+    for words in sorted({-(-n // _WORD) for n in longest}):
+        _ops.warm_frontier_join(params.join_min_sessions, words)
+
+
+def join_programs(params: MiningParams) -> int:
+    """How many frontier-join programs this process has made (0 unless
+    ``params.use_kernel``)."""
+    if not params.use_kernel:
+        return 0
+    from repro.kernels.bitmap_support import ops as _ops
+
+    return _ops.programs_made()
 
 
 def _frontier_join(

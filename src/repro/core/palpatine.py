@@ -14,12 +14,11 @@ from typing import Any, Optional, Sequence
 
 from .backstore import Clock, SimulatedDKVStore
 from .cache import TwoSpaceCache
-from .decision import build_engine
+from .decision import VectorizedPrefetchEngine
 from .heuristics import HeuristicConfig
 from . import obs
 from .metastore import PatternMetastore
 from .obs import (
-    NULL_TRACER,
     METRIC_MINE_COLD_PROGRAMS,
     SPAN_CACHE,
     SPAN_DECISION,
@@ -33,12 +32,13 @@ from .obs import (
     EVENT_SHED,
 )
 from .mining import (
-    BITMAP_ALGOS,
     MiningParams,
+    Pattern,
     VerticalBitmaps,
     dynamic_floor_count,
-    mine,
+    join_programs,
     mine_dynamic_minsup,
+    warm_join,
 )
 from .ptree import PTreeIndex
 from .sessions import AccessLogger
@@ -76,11 +76,6 @@ class PalpatineConfig:
     # predictions are instantiated with the triggering request's row
     # ("a sequence of table and columns that are accessed for a given row")
     column_mining: bool = False
-    # prefetch decisions: the vectorized array engine walks all live
-    # contexts in one batched program per request (flat per-op cost as
-    # contexts multiply); False falls back to the scalar per-context
-    # tree-walk oracle — the two are differentially identical
-    use_vectorized: bool = True
     # online mining (§4.2): re-mine every N logged operations (None = offline);
     # an online client makes every device program a round can use in its
     # first round, so later rounds (inside a user's read) start none
@@ -107,17 +102,16 @@ class PalpatineClient:
                       TwoSpaceCache(self.cfg.cache_bytes, self.cfg.preemptive_frac))
         self.metastore = PatternMetastore(self.cfg.metastore_capacity,
                                           self.cfg.mining.max_len)
-        self.engine = build_engine(PTreeIndex.build([]), self.cfg.heuristic,
-                                   use_vectorized=self.cfg.use_vectorized,
-                                   backend=self.cfg.decision_backend)
+        self.engine = VectorizedPrefetchEngine(
+            PTreeIndex.build([]), self.cfg.heuristic,
+            backend=self.cfg.decision_backend)
         self.col_logger = AccessLogger(self.cfg.session_gap)
         # column patterns are instantiated with the *current* request's row,
         # so they are always walked progressively (one confirmed step ->
         # next level), regardless of the main heuristic
-        self.col_engine = build_engine(
+        self.col_engine = VectorizedPrefetchEngine(
             PTreeIndex.build([]),
             HeuristicConfig("fetch_progressive", progressive_depth=2),
-            use_vectorized=self.cfg.use_vectorized,
             backend=self.cfg.decision_backend)
         self.col_metastore: Optional[PatternMetastore] = None
         self._ops_since_mine = 0
@@ -146,7 +140,7 @@ class PalpatineClient:
         # Palpascope: share the store's tracer (NULL_TRACER unless
         # enable_tracing was called on the store/cluster), and have both
         # engines name the pattern behind every emitted prefetch target
-        self.tracer = getattr(store, "tracer", NULL_TRACER)
+        self.tracer = store.tracer
         self.engine.attribute = True
         self.col_engine.attribute = True
 
@@ -154,14 +148,9 @@ class PalpatineClient:
     # Client API (mirrors the store's get/put — transparent, §4.5)
     # ------------------------------------------------------------------
     def _demand_fetch(self, key, now: float):
-        """One demand read as a future: (value, completion_time).  Stores
-        without the futures API fall back to the blocking get."""
-        get_async = getattr(self.store, "get_async", None)
-        if get_async is None:
-            value, lat = self.store.get(key)
-            return value, now + lat
-        fut = get_async(key, now)
-        if getattr(fut, "timed_out", False):
+        """One demand read as a future: (value, completion_time)."""
+        fut = self.store.get_async(key, now)
+        if fut.timed_out:
             self.demand_timeouts += 1
         return fut.value(), fut.done_at
 
@@ -202,10 +191,7 @@ class PalpatineClient:
                     self.cache.put_demand(iid, value, len(value))
 
             if self.cfg.prefetch_enabled:
-                gen_iid = self._begin_column_walk(container)
-                self._prefetch(iid, now)
-                if gen_iid is not None:
-                    self._prefetch_columns(container, gen_iid, now)
+                self._decide(container, iid, now)
             self._maybe_online_mine()
             self.clock.advance(latency)
             sp.finish(now + latency)
@@ -260,15 +246,10 @@ class PalpatineClient:
                 keys = [k for _, _, k in misses]
                 dsp = tr.span(SPAN_DEMAND, now)
                 try:
-                    multi_async = getattr(self.store, "multi_get_async", None)
-                    if multi_async is None:
-                        vals, lat = self.store.multi_get(keys)
-                        batch_done = now + lat
-                    else:
-                        fut = multi_async(keys, now)
-                        vals, batch_done = fut.result()
-                        if getattr(fut, "timed_out", False):
-                            self.demand_timeouts += 1
+                    fut = self.store.multi_get_async(keys, now)
+                    vals, batch_done = fut.result()
+                    if fut.timed_out:
+                        self.demand_timeouts += 1
                     dsp.finish(batch_done)
                 finally:
                     tr.end(dsp)
@@ -281,10 +262,7 @@ class PalpatineClient:
             latency = (done_at - now) + CACHE_OVERHEAD * len(containers)
             if self.cfg.prefetch_enabled:
                 for iid, container in zip(iids, containers):
-                    gen_iid = self._begin_column_walk(container)
-                    self._prefetch(iid, now)
-                    if gen_iid is not None:
-                        self._prefetch_columns(container, gen_iid, now)
+                    self._decide(container, iid, now)
             self._maybe_online_mine()
             self.clock.advance(latency)
             sp.finish(now + latency)
@@ -326,34 +304,6 @@ class PalpatineClient:
     # ------------------------------------------------------------------
     # Mining control (stage 1 -> stage 2 in the benchmarks)
     # ------------------------------------------------------------------
-    def _cached_bitmaps(self, logger: AccessLogger, db, count: int,
-                        which: str) -> Optional[VerticalBitmaps]:
-        """The previous run's packed bitmaps, iff the logged tail is
-        unchanged (same event count, session count, vocabulary and support
-        count) — an online re-mine over an idle backlog then skips the
-        scatter/pack entirely.  Returns None on miss (no build here: the
-        dynamic-minsup path only pays the floor build if a decay retry
-        actually happens)."""
-        if self.cfg.algo not in BITMAP_ALGOS:
-            return None
-        fp = (logger.n_events, len(db.sessions), db.n_items, count)
-        hit = self._vb_cache.get(which)
-        return hit[1] if hit is not None and hit[0] == fp else None
-
-    def _build_bitmaps(self, logger: AccessLogger, db, count: int,
-                       which: str) -> Optional[VerticalBitmaps]:
-        """Build + cache packed bitmaps for ``db`` at support ``count``."""
-        if self.cfg.algo not in BITMAP_ALGOS:
-            return None
-        vb = VerticalBitmaps(db, count)
-        fp = (logger.n_events, len(db.sessions), db.n_items, count)
-        self._vb_cache[which] = (fp, vb)
-        return vb
-
-    def _floor_count(self, db, floor: float) -> int:
-        return dynamic_floor_count(
-            self.cfg.mining, len(db), self.cfg.dynamic_minsup_start, floor)
-
     def _params(self) -> MiningParams:
         """The mining parameters of a round: an online round's kernel join
         pads its sessions to the tail, so every round shares its shapes."""
@@ -373,14 +323,7 @@ class PalpatineClient:
         return ([self.engine, self.col_engine] if self.cfg.column_mining
                 else [self.engine])
 
-    def _join_programs(self) -> int:
-        if not self.cfg.mining.use_kernel:
-            return 0
-        from repro.kernels.bitmap_support import ops
-
-        return ops.programs_made()
-
-    def _warm(self, dbs) -> None:
+    def _warm(self, params: MiningParams, dbs) -> None:
         """Make every device program a round of this configuration can
         use: the frontier join's ladder for the tail's sessions and each
         log's longest session, and the decision walk's node ladder up to
@@ -388,72 +331,88 @@ class PalpatineClient:
         ``max_len`` items)."""
         self._warmed = True
         with obs.host_profile.span(SPAN_HOST_MINE_WARM):
-            if self.cfg.mining.use_kernel:
-                from repro.kernels.bitmap_support import ops
-
-                longest = (max(map(len, db.sessions), default=1) for db in dbs)
-                for words in sorted({-(-n // 32) for n in longest}):
-                    ops.warm_frontier_join(self.cfg.online_tail_sessions,
-                                           words)
+            warm_join(params, dbs)
             nodes = self.cfg.metastore_capacity * self.cfg.mining.max_len
             for eng in self._engines():
-                warm = getattr(eng, "warm_walk", None)
-                if warm is not None:
-                    warm(nodes)
+                eng.warm_walk(nodes)
 
-    def mine_now(self, use_dynamic_minsup: bool = True) -> int:
+    def mine_now(self) -> int:
         """Run the Data Mining Engine on the backlog, furnish the metastore,
         rebuild the probabilistic trees.  Returns #patterns stored."""
         with obs.host_profile.span(SPAN_HOST_MINE):
-            return self._mine_round(use_dynamic_minsup)
+            t0 = obs.host_clock()
+            params = self._params()
+            col_db = (self._tail(self.col_logger) if self.cfg.column_mining
+                      else None)
+            db = self._tail(self.logger)
+            if self.cfg.online_mine_every is not None and not self._warmed:
+                self._warm(params, [db] if col_db is None else [db, col_db])
+            joins = join_programs(params)
+            if col_db is not None:
+                # a column pattern needs >= 2 sessions whatever the floor;
+                # each round installs a fresh column metastore
+                floor = max(self.cfg.dynamic_minsup_floor,
+                            2.0 / max(len(col_db), 1))
+                ms = PatternMetastore(self.cfg.metastore_capacity,
+                                      self.cfg.mining.max_len)
+                self._install(self.col_engine, ms, self._mine_log(
+                    self.col_logger, col_db, "col", floor, params))
+                self.col_metastore = ms
+            patterns = self._mine_log(self.logger, db, "main",
+                                      self.cfg.dynamic_minsup_floor, params)
+            self.mining_runs += 1
+            self._last_mine_events = self.logger.n_events
+            t1 = self._install(self.engine, self.metastore, patterns)
+            self.mining_wall_time += t1 - t0
+            self._last_mine_generation = self.metastore.generation
+            if self._warmed:
+                cold = join_programs(params) - joins + sum(
+                    not eng.walk_program_made() for eng in self._engines())
+                self.cold_programs += cold
+                obs.host_profile.count(METRIC_MINE_COLD_PROGRAMS, cold)
+            return len(self.metastore)
 
-    def _mine_round(self, use_dynamic_minsup: bool) -> int:
-        t0 = obs.host_clock()
-        col_db = self._tail(self.col_logger) if self.cfg.column_mining else None
-        db = self._tail(self.logger)
-        if self.cfg.online_mine_every is not None and not self._warmed:
-            self._warm([db] if col_db is None else [db, col_db])
-        joins = self._join_programs()
-        params = self._params()
-        if col_db is not None:
-            self._mine_columns(col_db, params, use_dynamic_minsup)
-        if use_dynamic_minsup:
-            floor_count = self._floor_count(db, self.cfg.dynamic_minsup_floor)
-            vb = self._cached_bitmaps(self.logger, db, floor_count, "main")
-            patterns, _ = mine_dynamic_minsup(
-                db, params, self.cfg.algo,
-                start=self.cfg.dynamic_minsup_start,
-                floor=self.cfg.dynamic_minsup_floor,
-                min_patterns=self.cfg.min_patterns,
-                vb=vb,
-                vb_factory=lambda: self._build_bitmaps(
-                    self.logger, db, floor_count, "main"),
-            )
-        else:
-            count = self.cfg.mining.minsup_count(len(db))
-            vb = self._cached_bitmaps(self.logger, db, count, "main")
-            if vb is None:
-                vb = self._build_bitmaps(self.logger, db, count, "main")
-            patterns = mine(db, params, self.cfg.algo, vb=vb)
-        self.mining_runs += 1
-        self._last_mine_events = self.logger.n_events
+    def _mine_log(self, logger: AccessLogger, db, which: str, floor: float,
+                  params: MiningParams) -> list[Pattern]:
+        """One log's patterns for a round: the dynamic-minsup descent
+        (paper §4.2) from ``dynamic_minsup_start`` down to ``floor``, kept
+        to those seen in two sessions or more.  The packed bitmaps of the
+        log's previous round (``which``: "main" or "col") are reused iff
+        its tail is unchanged (same event count, session count, vocabulary
+        and floor count), so a re-mine over an idle backlog skips the
+        scatter/pack; otherwise they are built only if the descent
+        retries below its start."""
+        count = dynamic_floor_count(
+            self.cfg.mining, len(db), self.cfg.dynamic_minsup_start, floor)
+        fp = (logger.n_events, len(db.sessions), db.n_items, count)
+        hit = self._vb_cache.get(which)
+
+        def build() -> VerticalBitmaps:
+            vb = VerticalBitmaps(db, count)
+            self._vb_cache[which] = (fp, vb)
+            return vb
+
+        patterns, _ = mine_dynamic_minsup(
+            db, params, self.cfg.algo,
+            start=self.cfg.dynamic_minsup_start,
+            floor=floor,
+            min_patterns=self.cfg.min_patterns,
+            vb=hit[1] if hit is not None and hit[0] == fp else None,
+            vb_factory=build)
         # a sequence observed once is not a pattern: support >= 2 sessions
-        patterns = [p for p in patterns if p.support >= 2]
-        t1 = obs.host_clock()
+        return [p for p in patterns if p.support >= 2]
+
+    def _install(self, engine: VectorizedPrefetchEngine,
+                 metastore: PatternMetastore, patterns: list) -> float:
+        """Put a round's patterns in ``metastore`` and their trees in
+        ``engine``; returns the host clock at the end."""
+        t0 = obs.host_clock()
         with obs.host_profile.span(SPAN_HOST_MINE_REBUILD):
-            self.metastore.populate(patterns)
-            self.engine.replace_index(PTreeIndex.build(self.metastore))
-        t2 = obs.host_clock()
-        self.rebuild_wall_time += t2 - t1
-        self.mining_wall_time += t2 - t0
-        self._last_mine_generation = self.metastore.generation
-        if self._warmed:
-            cold = self._join_programs() - joins + sum(
-                not getattr(eng, "walk_program_made", lambda: True)()
-                for eng in self._engines())
-            self.cold_programs += cold
-            obs.host_profile.count(METRIC_MINE_COLD_PROGRAMS, cold)
-        return len(self.metastore)
+            metastore.populate(patterns)
+            engine.replace_index(PTreeIndex.build(metastore))
+        t1 = obs.host_clock()
+        self.rebuild_wall_time += t1 - t0
+        return t1
 
     def backlog_unchanged_since_mine(self) -> bool:
         """True when no read has been logged since the last ``mine_now``
@@ -483,36 +442,6 @@ class PalpatineClient:
             return (key[0], None, key[2])     # (table, *, column)
         return key
 
-    def _mine_columns(self, db, params: MiningParams,
-                      use_dynamic_minsup: bool = True) -> None:
-        floor = max(self.cfg.dynamic_minsup_floor, 2.0 / max(len(db), 1))
-        if use_dynamic_minsup:
-            floor_count = self._floor_count(db, floor)
-            vb = self._cached_bitmaps(self.col_logger, db, floor_count, "col")
-            patterns, _ = mine_dynamic_minsup(
-                db, params, self.cfg.algo,
-                start=self.cfg.dynamic_minsup_start,
-                floor=floor,
-                min_patterns=self.cfg.min_patterns,
-                vb=vb,
-                vb_factory=lambda: self._build_bitmaps(
-                    self.col_logger, db, floor_count, "col"))
-        else:
-            count = self.cfg.mining.minsup_count(len(db))
-            vb = self._cached_bitmaps(self.col_logger, db, count, "col")
-            if vb is None:
-                vb = self._build_bitmaps(self.col_logger, db, count, "col")
-            patterns = mine(db, params, self.cfg.algo, vb=vb)
-        patterns = [p for p in patterns if p.support >= 2]
-        t0 = obs.host_clock()
-        with obs.host_profile.span(SPAN_HOST_MINE_REBUILD):
-            ms = PatternMetastore(self.cfg.metastore_capacity,
-                                  self.cfg.mining.max_len)
-            ms.populate(patterns)
-            self.col_metastore = ms
-            self.col_engine.replace_index(PTreeIndex.build(ms))
-        self.rebuild_wall_time += obs.host_clock() - t0
-
     def _begin_column_walk(self, container) -> Optional[int]:
         """The column engine's item for a read of a ``(table, row,
         column)`` cell, with the engine's device walk for it begun, so
@@ -527,6 +456,14 @@ class PalpatineClient:
         gen_iid = self.col_logger.db.item_id(self._generalize(container))
         self.col_engine.begin_walk(gen_iid)
         return gen_iid
+
+    def _decide(self, container, iid: int, now: float) -> None:
+        """Prefetch for one read: the column engine's walk is begun
+        first, so that it runs under the main engine's."""
+        gen_iid = self._begin_column_walk(container)
+        self._prefetch(iid, now)
+        if gen_iid is not None:
+            self._prefetch_columns(container, gen_iid, now)
 
     def _prefetch_columns(self, container, gen_iid: int,
                           now: float) -> None:
@@ -674,12 +611,8 @@ class BaselineClient:
     def read(self, container) -> tuple[Any, float]:
         key = container.key() if hasattr(container, "key") else container
         now = self.clock.now
-        get_async = getattr(self.store, "get_async", None)
-        if get_async is None:
-            value, latency = self.store.get(key)
-        else:
-            fut = get_async(key, now)
-            value, latency = fut.value(), fut.done_at - now
+        fut = self.store.get_async(key, now)
+        value, latency = fut.value(), fut.done_at - now
         self.clock.advance(latency)
         return value, latency
 
@@ -688,13 +621,8 @@ class BaselineClient:
         the batch completes when the slowest node lands."""
         keys = [c.key() if hasattr(c, "key") else c for c in containers]
         now = self.clock.now
-        multi_async = getattr(self.store, "multi_get_async", None)
-        if multi_async is None:
-            values, latency = self.store.multi_get(keys)
-        else:
-            fut = multi_async(keys, now)
-            values, done_at = fut.result()
-            latency = done_at - now
+        values, done_at = self.store.multi_get_async(keys, now).result()
+        latency = done_at - now
         self.clock.advance(latency)
         return values, latency
 

@@ -115,9 +115,6 @@ class PrefetcherConfig:
     mine_every_sessions: int = 64
     # a read racing an in-flight prefetch demand-fetches past this wait
     prefetch_wait_cap: float = 2e-3
-    # batched decision engine (flat per-op cost across live contexts);
-    # False = scalar per-context oracle, differentially identical
-    use_vectorized: bool = True
     min_patterns: int = 8
 
 
@@ -146,7 +143,6 @@ class ExpertPrefetcher:
             mining=self.cfg.mining,
             session_gap=float("inf"),          # explicit end_session cuts
             prefetch_wait_cap=self.cfg.prefetch_wait_cap,
-            use_vectorized=self.cfg.use_vectorized,
             min_patterns=self.cfg.min_patterns,
         )
         dkv = store.dkv
@@ -232,10 +228,10 @@ class ExpertPrefetcher:
             self._sessions_since_mine = 0
             self.mine_now()
 
-    def mine_now(self, use_dynamic_minsup: bool = True) -> int:
+    def mine_now(self) -> int:
         """Mine the routing backlog; gossip through the cluster exchange
         when one is attached (publish ours, pull the cluster's)."""
-        self.client.mine_now(use_dynamic_minsup)
+        self.client.mine_now()
         if self.exchange is not None:
             self.exchange.publish(self.client)
             self.exchange.pull(self.client)

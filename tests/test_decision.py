@@ -27,7 +27,6 @@ from repro.core import (
     PTree,
     PTreeIndex,
     VectorizedPrefetchEngine,
-    build_engine,
 )
 from repro.core.heuristics import PrefetchContext
 
@@ -180,9 +179,9 @@ def test_divergence_kills_context_in_both():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("use_vectorized", [False, True],
+@pytest.mark.parametrize("engine", [PrefetchEngine, VectorizedPrefetchEngine],
                          ids=["scalar", "vectorized"])
-def test_saturation_evicts_stalest_not_newest(use_vectorized):
+def test_saturation_evicts_stalest_not_newest(engine):
     """At max_contexts, a fresh root match used to be silently dropped —
     its follow-up progressive waves never fired.  Now the stalest
     context is evicted and the new one keeps waving.
@@ -195,8 +194,7 @@ def test_saturation_evicts_stalest_not_newest(use_vectorized):
         Pattern((1, 5, 6, 7), 10),
     ])
     cfg = HeuristicConfig("fetch_progressive", progressive_depth=1)
-    eng = build_engine(index, cfg, max_contexts=1,
-                       use_vectorized=use_vectorized)
+    eng = engine(index, cfg, max_contexts=1)
     assert eng.on_request(0) == [1]      # ctx A opened (saturated now)
     # A advances (wave [2]) AND root 1 opens ctx B, evicting live A
     assert eng.on_request(1) == [2, 5]
@@ -209,9 +207,9 @@ def test_saturation_evicts_stalest_not_newest(use_vectorized):
     assert eng.n_live == 0
 
 
-@pytest.mark.parametrize("use_vectorized", [False, True],
+@pytest.mark.parametrize("engine", [PrefetchEngine, VectorizedPrefetchEngine],
                          ids=["scalar", "vectorized"])
-def test_eviction_removes_oldest_keeps_rest(use_vectorized):
+def test_eviction_removes_oldest_keeps_rest(engine):
     """Three overlapping chains keep two contexts live when the third
     root arrives; the oldest is evicted, the newcomer is appended, and
     both survivors keep waving.  (Live progressive contexts are all
@@ -223,8 +221,7 @@ def test_eviction_removes_oldest_keeps_rest(use_vectorized):
         Pattern((4, 8, 7), 10),
     ])
     cfg = HeuristicConfig("fetch_progressive", progressive_depth=1)
-    eng = build_engine(index, cfg, max_contexts=2,
-                       use_vectorized=use_vectorized)
+    eng = engine(index, cfg, max_contexts=2)
     assert eng.on_request(0) == [1]          # ctx A
     assert eng.on_request(1) == [2]
     assert eng.on_request(2) == [3]          # ctx B opens (wave deduped)
@@ -237,15 +234,14 @@ def test_eviction_removes_oldest_keeps_rest(use_vectorized):
     assert eng.n_live == 1
 
 
-@pytest.mark.parametrize("use_vectorized", [False, True],
+@pytest.mark.parametrize("engine", [PrefetchEngine, VectorizedPrefetchEngine],
                          ids=["scalar", "vectorized"])
-def test_root_reconfirm_dedupes_instead_of_reopening(use_vectorized):
+def test_root_reconfirm_dedupes_instead_of_reopening(engine):
     """Re-requesting the root an open context sits on must neither kill
     it, duplicate it, nor replay the initial wave."""
     index = PTreeIndex.build([Pattern((0, 1, 2, 3), 10)])
     cfg = HeuristicConfig("fetch_progressive", progressive_depth=1)
-    eng = build_engine(index, cfg, max_contexts=4,
-                       use_vectorized=use_vectorized)
+    eng = engine(index, cfg, max_contexts=4)
     assert eng.on_request(0) == [1]
     for _ in range(3):                   # hammer the root
         assert eng.on_request(0) == []   # no recomputed wave
@@ -253,9 +249,9 @@ def test_root_reconfirm_dedupes_instead_of_reopening(use_vectorized):
     assert eng.on_request(1) == [2]      # still advances normally
 
 
-@pytest.mark.parametrize("use_vectorized", [False, True],
+@pytest.mark.parametrize("engine", [PrefetchEngine, VectorizedPrefetchEngine],
                          ids=["scalar", "vectorized"])
-def test_root_reconfirm_survives_alongside_advancing_context(use_vectorized):
+def test_root_reconfirm_survives_alongside_advancing_context(engine):
     """The stay rule composes with batch advancement: one context
     advances on the item while another sits on it as a re-confirmed
     root."""
@@ -264,8 +260,7 @@ def test_root_reconfirm_survives_alongside_advancing_context(use_vectorized):
         Pattern((0, 1, 2), 10),          # tree rooted at 0
     ])
     cfg = HeuristicConfig("fetch_progressive", progressive_depth=1)
-    eng = build_engine(index, cfg, max_contexts=4,
-                       use_vectorized=use_vectorized)
+    eng = engine(index, cfg, max_contexts=4)
     assert eng.on_request(5) == [0]          # ctx B on the chain
     assert eng.on_request(0) == [0, 1]       # B advances; ctx A opens
     assert eng.n_live == 2
@@ -296,9 +291,8 @@ def test_do_nothing_contexts_never_open():
     # engine built atop an index where one root would be depth-0 if the
     # build guard regressed
     idx = PTreeIndex.build([Pattern((5,), 100), Pattern((0, 1), 10)])
-    for use_vectorized in (False, True):
-        eng = build_engine(idx, HeuristicConfig("fetch_progressive"),
-                           use_vectorized=use_vectorized)
+    for engine in (PrefetchEngine, VectorizedPrefetchEngine):
+        eng = engine(idx, HeuristicConfig("fetch_progressive"))
         assert eng.on_request(5) == []
         assert eng.n_live == 0
 

@@ -160,9 +160,7 @@ def test_online_mining_adapts_to_new_patterns():
     assert ids & in_trees
 
 
-@pytest.mark.parametrize("use_dynamic_minsup", [True, False])
-def test_mining_wall_time_covers_the_whole_round(monkeypatch,
-                                                 use_dynamic_minsup):
+def test_mining_wall_time_covers_the_whole_round(monkeypatch):
     """A round's host time runs from ``mine_now``'s entry, column round
     included, to the new trees' install; ``rebuild_wall_time`` is the
     install of both metastores.  A fake clock that only mining and tree
@@ -188,12 +186,12 @@ def test_mining_wall_time_covers_the_whole_round(monkeypatch,
         for key in sess:
             client.read(key)
         client.end_session()
-    name = "mine_dynamic_minsup" if use_dynamic_minsup else "mine"
-    monkeypatch.setattr(pal, name, timed(getattr(pal, name), 10.0, mined))
+    monkeypatch.setattr(pal, "mine_dynamic_minsup",
+                        timed(pal.mine_dynamic_minsup, 10.0, mined))
     monkeypatch.setattr(PTreeIndex, "build",
                         staticmethod(timed(PTreeIndex.build, 1.0, built)))
     for _ in range(2):
-        client.mine_now(use_dynamic_minsup)
+        client.mine_now()
     assert client.col_metastore is not None and len(client.metastore) > 0
     assert len(mined) == 4 and len(built) == 4    # column + main, twice
     assert client.mining_runs == 2

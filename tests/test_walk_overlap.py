@@ -175,8 +175,7 @@ def test_begin_walk_is_a_no_op_off_the_device_path(monkeypatch):
     scalar = PrefetchEngine(index, host.cfg, 8)
     walks = Walks(monkeypatch)
     for item in seqb_stream(5, index, n_ops=40):
-        for eng in (host, scalar):
-            eng.begin_walk(item)
+        host.begin_walk(item)
         assert host.on_request(item) == scalar.on_request(item)
     dev.begin_walk(0)                   # no live context yet
     assert dev._pending is None
