@@ -42,11 +42,20 @@ in interpret mode on CPU); the DFS spill path uses the per-prefix
 
 Incremental dynamic minsup
 --------------------------
-``mine_dynamic_minsup`` builds the packed ``VerticalBitmaps`` **once** at the
-floor support and re-thresholds per decay retry instead of re-scattering the
-session matrix per minsup step; callers that re-mine an unchanged backlog
-(``PalpatineClient.mine_now``) can pass a cached ``vb`` to skip the build
-entirely.  A prebuilt ``vb`` must have been constructed at a support count
+``mine_dynamic_minsup`` descends minsup from its start toward its floor
+until a rung holds ``min_patterns`` patterns.  A frontier pass at one
+rung visits every sequence frequent at any rung above it, with its
+support and its best forward extension's support, so each higher rung's
+answer is a threshold of that one pass (``_emit``), in the walk's own
+order.  A log's round therefore makes one pass, at the rung the log's
+previous round settled on (``PalpatineClient`` keeps it per log), reads
+every rung from the start down to it, and mines lower, one pass a rung,
+only if none of them holds enough; a log's first round mines each rung
+in turn.  The packed ``VerticalBitmaps`` of the passes are built once
+a round, at the floor support (a first round's start rung builds its
+own, so a backlog satisfied there never pays the floor build), and a
+caller that re-mines an unchanged backlog (``PalpatineClient.mine_now``)
+passes its cached ``vb`` to skip the build.  A prebuilt ``vb`` must have been constructed at a support count
 no higher than the one mined at — rows below the current threshold are
 filtered inside the engine.
 """
@@ -416,39 +425,33 @@ def _dfs_mine(
     return out
 
 
-def _frontier_mine(
-    db: SequenceDatabase,
-    params: MiningParams,
-    maximal_only: bool,
-    vb: Optional[VerticalBitmaps] = None,
-) -> list[Pattern]:
-    """Level-synchronous frontier miner (see module docstring).
+@dataclasses.dataclass(frozen=True)
+class _Level:
+    """One depth of a frontier pass: its frequent sequences in walk order
+    (item ids lexicographic), their supports, and the most sessions any
+    one-item forward extension of each reaches (0 at ``max_len``, where
+    none is joined)."""
 
-    Byte-identical Pattern output to :func:`_dfs_mine` (set-wise; emission
-    order is per-level instead of depth-first)."""
-    msc = params.minsup_count(len(db))
-    if vb is None:
-        vb = VerticalBitmaps(db, msc)
+    patterns: list
+    support: np.ndarray
+    ext: np.ndarray
+
+
+def _frontier_levels(
+    vb: VerticalBitmaps, params: MiningParams, msc: int
+) -> Optional[list[_Level]]:
+    """The level-synchronous frontier walk at support count ``msc`` (see
+    module docstring), keeping every frequent sequence it visits, level
+    by level.  None when even a single prefix's ``K×S×W`` join exceeds
+    ``params.frontier_budget`` (a walk-invariant quantity, so a
+    whole-walk decision): the caller spills to the DFS walker."""
     rows = np.nonzero(vb.freq_support >= msc)[0]
-    out: list[Pattern] = []
     if rows.size == 0:
-        return out
-
+        return []
     cand = vb.bits[rows]                      # (K, S, W), fixed for the walk
     cand_items = vb.freq_items[rows]
-    k_items = rows.size
-    per_prefix_bytes = k_items * vb.n_sessions * vb.n_words * 4
-    if per_prefix_bytes > params.frontier_budget:
-        # even a single prefix's K×S×W join exceeds the byte cap (the
-        # quantity is walk-invariant, so this is a whole-walk decision):
-        # fall back to the per-node DFS walker
-        for i, r in enumerate(rows):
-            _dfs_expand(
-                vb, params, msc, rows, cand_items,
-                (int(cand_items[i]),), vb.bits[r], int(vb.freq_support[r]),
-                maximal_only, out,
-            )
-        return out
+    if rows.size * vb.n_sessions * vb.n_words * 4 > params.frontier_budget:
+        return None
 
     patterns: list[tuple] = [(int(it),) for it in cand_items]
     fbits = cand                              # depth-1 frontier = item bitmaps
@@ -463,24 +466,19 @@ def _frontier_mine(
     # contiguous walks keep the full candidate set.
     narrow = params.maxgap is None
     allowed: Optional[np.ndarray] = None      # (P, K) mask; None = all
-    depth = 1
+    levels: list[_Level] = []
     while patterns:
-        if depth >= params.max_len:
-            # no further expansion possible: every frontier pattern is
-            # emitted (the DFS likewise skips the forward-extension check
-            # at max_len)
-            if depth >= params.min_len:
-                out.extend(Pattern(p, int(s)) for p, s in zip(patterns, fsups))
+        if len(levels) + 1 >= params.max_len:
+            # no further expansion possible (the DFS likewise skips the
+            # forward-extension check at max_len)
+            levels.append(_Level(patterns, fsups, np.zeros_like(fsups)))
             break
         # extension slots for the whole frontier, once per level (reused
         # across every support chunk below)
         slots = vb.extension_slots(fbits, params.maxgap)
         sup = _frontier_support(slots, cand, params, allowed=allowed)  # (P, K)
+        levels.append(_Level(patterns, fsups, sup.max(axis=1)))
         surv = sup >= msc
-        has_ext = surv.any(axis=1)                         # maximality mask
-        if depth >= params.min_len:
-            for p in np.nonzero(~has_ext)[0] if maximal_only else range(len(patterns)):
-                out.append(Pattern(patterns[p], int(fsups[p])))
         pidx, kidx = np.nonzero(surv)
         if pidx.size == 0:
             break
@@ -494,8 +492,47 @@ def _frontier_mine(
         if narrow:
             # child (p, k) inherits p's surviving extension row
             allowed = surv[pidx]
-        depth += 1
+    return levels
+
+
+def _emit(
+    levels: list[_Level], params: MiningParams, msc: int, maximal_only: bool
+) -> list[Pattern]:
+    """The frontier miner's output at support count ``msc``, from a pass
+    at that count or below: the sequences of ``min_len`` items or more
+    with support >= ``msc`` (with ``maximal_only``, those with no
+    extension that reaches ``msc``), level by level in walk order.  The
+    frontier at ``msc`` is the pass's frequent sequences that reach it,
+    in the same order, so this is the walk's own emission."""
+    out: list[Pattern] = []
+    for depth, lv in enumerate(levels, 1):
+        if depth < params.min_len:
+            continue
+        keep = lv.support >= msc
+        if maximal_only:
+            keep &= lv.ext < msc
+        out.extend(Pattern(lv.patterns[p], int(lv.support[p]))
+                   for p in np.nonzero(keep)[0])
     return out
+
+
+def _frontier_mine(
+    db: SequenceDatabase,
+    params: MiningParams,
+    maximal_only: bool,
+    vb: Optional[VerticalBitmaps] = None,
+) -> list[Pattern]:
+    """Level-synchronous frontier miner (see module docstring).
+
+    Byte-identical Pattern output to :func:`_dfs_mine` (set-wise; emission
+    order is per-level instead of depth-first)."""
+    msc = params.minsup_count(len(db))
+    if vb is None:
+        vb = VerticalBitmaps(db, msc)
+    levels = _frontier_levels(vb, params, msc)
+    if levels is None:
+        return _dfs_mine(db, params, maximal_only, vb)
+    return _emit(levels, params, msc, maximal_only)
 
 
 # ---------------------------------------------------------------------------
@@ -734,6 +771,25 @@ def dynamic_floor_count(
     ).minsup_count(n_sessions)
 
 
+def _ladder(start: float, floor: float, decay: float) -> list[float]:
+    """The descent's rungs: ``start``, decayed until it reaches ``floor``
+    (the first rung at or below it is the last)."""
+    rungs = [start]
+    while rungs[-1] > floor:
+        rungs.append(max(floor, rungs[-1] * decay))
+    return rungs
+
+
+def _read_rung(
+    levels: list[_Level], params: MiningParams, n_sessions: int, algo: str
+) -> list[Pattern]:
+    """``mine(db, params, algo)`` for a bitmap algorithm, read from a
+    frontier pass over ``db`` at ``params.minsup`` or below."""
+    maximal = algo == "vmsp"
+    out = _emit(levels, params, params.minsup_count(n_sessions), maximal)
+    return maximal_filter(out, params.maxgap) if maximal else out
+
+
 def mine_dynamic_minsup(
     db: SequenceDatabase,
     params: MiningParams,
@@ -744,34 +800,62 @@ def mine_dynamic_minsup(
     min_patterns: int = 16,
     vb: Optional[VerticalBitmaps] = None,
     vb_factory: Optional[Callable[[], VerticalBitmaps]] = None,
+    last: Optional[float] = None,
 ) -> tuple[list[Pattern], float]:
     """Paper §4.2: start with a high minsup and decay it until enough
-    frequent sequences are discovered.  Returns (patterns, used_minsup).
+    frequent sequences are discovered.  Returns (patterns, used_minsup):
+    the first rung from ``start`` down with ``min_patterns`` patterns,
+    else the floor.
 
-    Incremental: for the bitmap algorithms the packed ``VerticalBitmaps``
-    are built once at the *floor* support — lazily, on the first decay — and
-    re-thresholded per retry (every retry mines at minsup >= floor, so the
-    floor-level bitmaps are a superset of what each retry needs; a backlog
-    satisfied at ``start`` never pays the floor build).  Pass ``vb`` — built
-    at or below the floor count (:func:`dynamic_floor_count`) — to reuse
-    bitmaps across calls on an unchanged backlog, or ``vb_factory`` to keep
-    the build lazy while still capturing it for caching (it is only invoked
-    if a decay retry actually happens, and must build at that same count).
+    ``last`` is the minsup the log's previous descent settled on.  With
+    it, a bitmap algorithm makes one frontier pass, at the highest rung
+    not above ``last`` (the floor if every rung is), and reads every
+    rung from ``start`` down to it from that pass; only if none of them
+    holds enough does the descent mine on below, one pass a rung.
+    Without it (a log's first round), each rung is its own pass.  Either
+    way each rung's answer is exactly ``mine(db, rung, algo)``, so the
+    result does not depend on ``last``.  Each pass is one
+    ``palp.mine.passes`` on the host profile.
+
+    For the bitmap algorithms the packed ``VerticalBitmaps`` are built
+    once, at the floor support (:func:`dynamic_floor_count`): lazily, at
+    the first decay, when there is no ``last`` (a backlog satisfied at
+    ``start`` never pays the floor build), else before the pass.  Pass
+    ``vb``, built at or below that count, to reuse bitmaps across calls
+    on an unchanged backlog, or ``vb_factory`` to build them at that
+    count when needed and capture the build for caching.
     """
-    lazy_floor = vb is None and algo in BITMAP_ALGOS and len(db) > 0
-    minsup = start
-    patterns: list[Pattern] = []
-    while True:
-        patterns = mine(db, dataclasses.replace(params, minsup=minsup), algo, vb=vb)
-        if len(patterns) >= min_patterns or minsup <= floor:
-            return patterns, minsup
-        if lazy_floor and vb is None:
-            # first decay: build the floor-level bitmaps once and reuse them
-            # for every retry.  Deferred past the first mine so a backlog
-            # satisfied at `start` never pays the (much larger) floor build.
-            if vb_factory is not None:
-                vb = vb_factory()
-            else:
-                vb = VerticalBitmaps(
-                    db, dynamic_floor_count(params, len(db), start, floor))
-        minsup = max(floor, minsup * decay)
+    ladder = _ladder(start, floor, decay)
+    bitmap = algo in BITMAP_ALGOS and len(db) > 0
+
+    def floor_bitmaps() -> VerticalBitmaps:
+        if vb_factory is not None:
+            return vb_factory()
+        return VerticalBitmaps(
+            db, dynamic_floor_count(params, len(db), start, floor))
+
+    # rungs ladder[:read] are read from one pass at ladder[read - 1]
+    read, levels = 0, None
+    if bitmap and last is not None:
+        read = next((i for i, m in enumerate(ladder) if m <= last),
+                    len(ladder) - 1) + 1
+        if vb is None:
+            vb = floor_bitmaps()
+        at = dataclasses.replace(params, minsup=ladder[read - 1])
+        levels = _frontier_levels(vb, at, at.minsup_count(len(db)))
+        if levels is None:                    # spills: a pass per rung
+            read = 0
+        else:
+            obs.host_profile.count(obs.METRIC_MINE_PASSES)
+    for i, minsup in enumerate(ladder):
+        rung = dataclasses.replace(params, minsup=minsup)
+        if i < read:
+            patterns = _read_rung(levels, rung, len(db), algo)
+        else:
+            obs.host_profile.count(obs.METRIC_MINE_PASSES)
+            patterns = mine(db, rung, algo, vb=vb)
+        if len(patterns) >= min_patterns or i == len(ladder) - 1:
+            break
+        if bitmap and vb is None:
+            vb = floor_bitmaps()              # first decay: built once
+    return patterns, minsup

@@ -38,12 +38,12 @@ Four instruments, one module:
   (decision engine, decision-walk kernel wrapper) and the mining round
   (``palp.mine`` around ``mine_now``, with its ``.bitmaps`` builds,
   ``.join`` calls, ``.rebuild`` of trees and forest and ``.warm`` of
-  its programs; the ``palp.mine.join_h2d_bytes`` and
-  ``palp.mine.cold_programs`` counters).  Off by default
-  through :data:`NULL_HOST_PROFILE`; :func:`set_host_profile` installs
-  a :class:`HostProfile`, each of whose spans is also a
-  ``jax.profiler.TraceAnnotation``, so it lands in a profiler trace on
-  the same clock as the device's operations.  :data:`host_clock` is
+  its programs; the ``palp.mine.join_h2d_bytes``,
+  ``palp.mine.cold_programs`` and ``palp.mine.passes`` counters).  Off
+  by default through :data:`NULL_HOST_PROFILE`;
+  :func:`set_host_profile` installs a :class:`HostProfile`, each of
+  whose spans is also a ``jax.profiler.TraceAnnotation``, so it lands
+  in a profiler trace on the same clock as the device's operations.  :data:`host_clock` is
   the one host clock of ``src/repro/core``.
 
 Sampling: ``Tracer(sample=1/N, seed=...)`` keeps a deterministic 1-in-N
@@ -141,6 +141,8 @@ METRIC_WALK_OVERLAPPED = "palp.walk.overlapped"
 # copies to the device, and programs a round made that set-up had not
 METRIC_MINE_JOIN_H2D_BYTES = "palp.mine.join_h2d_bytes"
 METRIC_MINE_COLD_PROGRAMS = "palp.mine.cold_programs"
+# frontier passes a dynamic-minsup descent makes (one per rung it mines)
+METRIC_MINE_PASSES = "palp.mine.passes"
 
 REGISTERED_NAMES = frozenset(
     v for k, v in list(globals().items())

@@ -129,6 +129,9 @@ class PalpatineClient:
         self._warmed = False
         # packed-bitmap reuse across mining runs: {"main"/"col": (fp, vb)}
         self._vb_cache: dict = {}
+        # the minsup each log's last round settled on: {"main"/"col": m},
+        # where its next round's one frontier pass starts
+        self._settled: dict = {}
         self._last_mine_events: Optional[int] = None
         self._last_mine_generation: Optional[int] = None
         #: demand reads that paid >= 1 replica ack timeout before landing
@@ -376,12 +379,13 @@ class PalpatineClient:
                   params: MiningParams) -> list[Pattern]:
         """One log's patterns for a round: the dynamic-minsup descent
         (paper §4.2) from ``dynamic_minsup_start`` down to ``floor``, kept
-        to those seen in two sessions or more.  The packed bitmaps of the
-        log's previous round (``which``: "main" or "col") are reused iff
-        its tail is unchanged (same event count, session count, vocabulary
-        and floor count), so a re-mine over an idle backlog skips the
-        scatter/pack; otherwise they are built only if the descent
-        retries below its start."""
+        to those seen in two sessions or more.  The descent makes one
+        frontier pass at the minsup the log's previous round (``which``:
+        "main" or "col") settled on, and reads the rungs above it from
+        that pass.  That round's packed bitmaps are reused iff the tail
+        is unchanged (same event count, session count, vocabulary and
+        floor count), so a re-mine over an idle backlog skips the
+        scatter/pack."""
         count = dynamic_floor_count(
             self.cfg.mining, len(db), self.cfg.dynamic_minsup_start, floor)
         fp = (logger.n_events, len(db.sessions), db.n_items, count)
@@ -392,13 +396,14 @@ class PalpatineClient:
             self._vb_cache[which] = (fp, vb)
             return vb
 
-        patterns, _ = mine_dynamic_minsup(
+        patterns, self._settled[which] = mine_dynamic_minsup(
             db, params, self.cfg.algo,
             start=self.cfg.dynamic_minsup_start,
             floor=floor,
             min_patterns=self.cfg.min_patterns,
             vb=hit[1] if hit is not None and hit[0] == fp else None,
-            vb_factory=build)
+            vb_factory=build,
+            last=self._settled.get(which))
         # a sequence observed once is not a pattern: support >= 2 sessions
         return [p for p in patterns if p.support >= 2]
 
